@@ -225,6 +225,9 @@ print(f"RESULT,{ru:.1f},{pu:.1f},{ok}")
 def bench_ring_collectives():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # a host-device rehearsal: pinned to the CPU, so the child never
+    # contends with this process (which imported JAX) for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     r = subprocess.run([sys.executable, "-c", RING_BENCH],
                        capture_output=True, text=True, timeout=480, env=env)
@@ -234,7 +237,8 @@ def bench_ring_collectives():
             row("ring_allreduce_8dev", float(ru),
                 f"xla_psum_us={pu};allclose={ok}")
             return
-    row("ring_allreduce_8dev", -1.0, f"failed:{r.stderr[-120:]}")
+    raise RuntimeError(f"ring_allreduce_8dev child failed "
+                       f"(rc {r.returncode}):\n{r.stderr[-2000:]}")
 
 
 def bench_train_throughput():
@@ -333,8 +337,8 @@ def bench_serve_throughput(out_path="BENCH_serve.json"):
     while eng_w.active:
         eng_w.step()
     wave_cc = eng_w.compile_counts()
-    assert wave_cc["suspend_many"] in (1, -1), wave_cc
-    assert wave_cc["resume_many"] in (1, -1), wave_cc
+    assert wave_cc["suspend_many"] == 1, wave_cc
+    assert wave_cc["resume_many"] == 1, wave_cc
 
     bench = {
         "decode_tokens_per_s": round(tps_new, 1),
@@ -492,7 +496,7 @@ def bench_movement(out_path="BENCH_movement.json"):
         "planned_over_legacy": round(ratio, 4),
         "within_5pct": bool(ratio <= 1.05),
         # deterministic trace-time-only guard: the planned bodies compile
-        # once each, however many moves ran (-1 = no jit-cache probe)
+        # once each, however many moves ran
         "planned_compile_counts": {"suspend": cc["suspend"],
                                    "resume": cc["resume"]},
         "snapshot_bytes": eng.snapshot_bytes,
@@ -593,9 +597,9 @@ def bench_sched(out_path="BENCH_sched.json"):
         # so each entry point compiles at most once per distinct wave width
         n_resume_shapes = len(set(resume_widths))
         n_suspend_shapes = len({w for w in suspend_widths if w > 1})
-        assert cc["resume_many"] in (-1, *range(n_resume_shapes + 1)), (
+        assert cc["resume_many"] in range(n_resume_shapes + 1), (
             pol, resume_widths, cc)
-        assert cc["suspend_many"] in (-1, *range(n_suspend_shapes + 1)), (
+        assert cc["suspend_many"] in range(n_suspend_shapes + 1), (
             pol, suspend_widths, cc)
         results[pol] = {
             **summary,
@@ -1226,17 +1230,17 @@ def _check_serve(b, errs):
         errs.append("serve: decode_tokens_per_s not positive")
     if not b["suspend_resume_gbps"] > 0:
         errs.append("serve: suspend_resume_gbps not positive")
-    if b["compile_counts"]["decode"] not in (1, -1):
+    if b["compile_counts"]["decode"] != 1:
         errs.append(f"serve: decode compiled "
                     f"{b['compile_counts']['decode']}x")
     for k in ("suspend_many_compiles", "resume_many_compiles"):
-        if b["wave"][k] not in (1, -1):
+        if b["wave"][k] != 1:
             errs.append(f"serve: {k}={b['wave'][k]}")
 
 
 def _check_movement(b, errs):
     for k, v in b["planned_compile_counts"].items():
-        if v not in (1, -1):
+        if v != 1:
             errs.append(f"movement: {k} compiled {v}x")
     if not b["modeled_advantage"] > 1:
         errs.append("movement: Table-1 advantage lost")
@@ -1257,9 +1261,9 @@ def _check_sched(b, errs):
         if r["decisions"]["resume_wave"] != len(widths):
             errs.append(f"sched: {pol} resume decisions != wave count")
         cc = r["compile_counts"]
-        if cc["resume_many"] not in (-1, *range(len(set(widths)) + 1)):
+        if cc["resume_many"] not in range(len(set(widths)) + 1):
             errs.append(f"sched: {pol} resume_many compiles {cc}")
-        if cc["decode"] not in (1, -1):
+        if cc["decode"] != 1:
             errs.append(f"sched: {pol} decode compiles {cc['decode']}")
     tr = b["cost_aware"].get("trace")
     if not tr or not tr.get("spans"):

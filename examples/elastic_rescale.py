@@ -4,7 +4,7 @@ substrate accelerates (checkpoint restore -> NamedSharding placement; on a
 live cluster the same plan runs as lisa_copy hop chains).
 
 Run:  PYTHONPATH=src python examples/elastic_rescale.py
-(Spawns subprocesses with 8 forced host devices.)
+(Spawns subprocesses with 8 forced host devices, pinned to the CPU.)
 """
 import os
 import subprocess
@@ -63,6 +63,7 @@ if __name__ == "__main__":
     d = tempfile.mkdtemp()
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               JAX_PLATFORMS="cpu",
                PYTHONPATH=SRC + os.pathsep + os.environ.get("PYTHONPATH", ""))
     for phase in (PHASE1, PHASE2):
         r = subprocess.run([sys.executable, "-c",

@@ -3,22 +3,5 @@
 Faithful DRAM-substrate reproduction + the paper's connectivity insight as a
 first-class distributed-runtime feature.  See DESIGN.md.
 """
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under experimental only; the distributed
-    # modules (core/lisa/rbm, train/pipeline, ...) target the stable name.
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _jax.shard_map = _shard_map
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.5 has no lax.axis_size; psum of a literal 1 folds to the static
-    # mesh-axis size under shard_map, which is all the callers need.
-    _jax.lax.axis_size = lambda axis_name: _jax.lax.psum(1, axis_name)
-
-if not hasattr(_jax.lax, "pvary"):
-    # pvary only adjusts newer jax's replication tracking; on jax < 0.5
-    # shard_map has no varying-axis bookkeeping, so it is the identity.
-    _jax.lax.pvary = lambda x, axis_name: x
 
 __version__ = "1.0.0"

@@ -37,7 +37,8 @@ from repro.roofline import hlo as RH
 
 # primitives whose presence inside a hot-path graph means a host round-trip
 HOST_TRANSFER_PRIMITIVES = frozenset({
-    "io_callback", "pure_callback", "debug_callback", "callback",
+    "io_callback", "pure_callback", "debug_callback", "debug_print",
+    "callback",
     "host_callback_call", "infeed", "outfeed",
 })
 

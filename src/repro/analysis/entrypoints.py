@@ -12,14 +12,15 @@ entry point               contract
 ``suspend_many``          donate store+sums (args 1,2); ONE dispatch/wave
 ``resume``                donate cache+store+fail (args 0,1,3); uint8-prsv
 ``resume_many``           donate cache+store+fail (args 0,1,3); ONE disp
-``migrate``               donate dst pool (arg 1); uint8-preserving
+``migrate_gather``        no donation; uint8-preserving
+``migrate``               donate dst pool (arg 0); uint8-preserving
 ``simulate_params``       pure simulator: no donation, no host transfer
 ========================  =========================================
 
 The suspend/resume signatures carry the checksum sidecar (PR 7): suspends
 also emit per-page sums; resumes also consume them and fold the verify
 verdict into a donated failure counter — still zero extra host transfers.
-The migrate executor takes the traced ``(mode, index, xor)`` fault operand
+The migrate scatter leg takes the traced ``(mode, index, xor)`` fault operand
 (NULL_FAULT on clean runs): one compilation serves clean and chaos runs.
 
 Everything is traced/lowered statically — no engine loop runs, no tokens
@@ -102,20 +103,27 @@ def engine_targets(engine) -> List[AuditTarget]:
 
 
 def cluster_targets(cluster) -> List[AuditTarget]:
-    """The migration route executor of a constructed cluster (>= 2
-    replicas), audited at wave width :data:`AUDIT_WAVE`."""
+    """The migration route's two jitted legs of a constructed cluster (>= 2
+    replicas), audited at wave width :data:`AUDIT_WAVE`: the gather leg that
+    reads the source pool on the source device, and the donating scatter leg
+    on the destination device."""
     if cluster.n_replicas < 2:
         return []
     if cluster._migrate_exec is None:
-        cluster._migrate_exec = cluster._build_migrate_exec()
+        cluster._build_migrate_exec()
     spp = cluster.page_spec.n_pages
     table = jnp.arange(AUDIT_WAVE * spp, dtype=jnp.int32)
     src = cluster.replicas[0].sessions.slow
     dst = cluster.replicas[1].sessions.slow
+    pages = jnp.zeros((AUDIT_WAVE * spp,) + dst.shape[-2:], dst.dtype)
     fault = jnp.asarray(NULL_FAULT)
-    return [AuditTarget(
-        "migrate", cluster._migrate_exec, (src, dst, table, table, fault),
-        EntryContract(donate=frozenset({1}), uint8_preserving=True))]
+    return [
+        AuditTarget(
+            "migrate_gather", cluster._migrate_gather, (src, table),
+            EntryContract(donate=frozenset(), uint8_preserving=True)),
+        AuditTarget(
+            "migrate", cluster._migrate_exec, (dst, table, pages, fault),
+            EntryContract(donate=frozenset({0}), uint8_preserving=True))]
 
 
 def controller_targets() -> List[AuditTarget]:

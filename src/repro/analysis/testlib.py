@@ -1,17 +1,12 @@
 """Shared dispatch/compile-count asserters for tests, benchmarks and CI.
 
 PRs 2–5 pinned the one-dispatch-per-wave and compile-once invariants as
-ad-hoc expressions (``eng.compile_counts()["decode"] in (1, -1)``) scattered
-across test files; this module is the ONE checker both the test suite and
-the ``repro-lint`` CI gate call, so the tolerance for the ``-1``
-probe-unavailable sentinel (jax builds without ``_cache_size``) lives in
-exactly one place.
+ad-hoc expressions scattered across test files; this module is the ONE
+checker both the test suite and the ``repro-lint`` CI gate call.
 """
 from __future__ import annotations
 
 from typing import Dict, Iterable, Mapping, Union
-
-UNKNOWN = -1      # compile_counts() sentinel: no jit cache-size probe
 
 
 def _counts(obj) -> Mapping[str, int]:
@@ -33,10 +28,8 @@ def _flatten(allowed) -> set:
 
 
 def compile_count_ok(count: int, *allowed: Union[int, Iterable[int]]) -> bool:
-    """True when ``count`` is one of ``allowed`` — or the probe-unavailable
-    sentinel, which asserters must treat as 'unknown', never as a
-    regression."""
-    return count == UNKNOWN or count in _flatten(allowed)
+    """True when ``count`` is one of ``allowed``."""
+    return count in _flatten(allowed)
 
 
 def assert_compile_count(obj, key: str, *allowed) -> None:
@@ -53,7 +46,7 @@ def assert_compile_count(obj, key: str, *allowed) -> None:
 def assert_compile_at_most(obj, key: str, bound: int) -> None:
     counts = _counts(obj)
     count = counts[key]
-    if count != UNKNOWN and count > bound:
+    if count > bound:
         raise AssertionError(f"{key} compiled {count}x > bound {bound} "
                              f"(full counts: {dict(counts)})")
 

@@ -114,7 +114,8 @@ def ring_scan(x: jax.Array, axis_name: str,
     idx = jax.lax.axis_index(axis_name)
     step = -1 if reverse else 1
     perm = _shift_perm(n, step)
-    init = jax.lax.pvary(init, (axis_name,))   # mark device-varying for scan
+    # mark device-varying for scan
+    init = jax.lax.pcast(init, (axis_name,), to="varying")
 
     def body(k, carry):
         acc, buf = carry

@@ -19,6 +19,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import lowered_for_platform
 
 NEG_INF = -1e30
 
@@ -86,8 +89,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     B, H, S, D = q.shape
     K, T = k.shape[1], k.shape[2]
     G = H // K
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     block_q = min(block_q, S)
     block_k = min(block_k, T)
     nq = -(-S // block_q)
@@ -109,9 +110,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     # GQA routing: q-head block bh -> kv row (batch * K + head // G).
     kv_row = lambda bh: (bh // H) * K + (bh % H) // G
 
-    from jax.experimental.pallas import tpu as pltpu
-
-    out = pl.pallas_call(
+    out = lowered_for_platform(functools.partial(
+        pl.pallas_call,
         kernel,
         grid=(B * H, nq, nk),
         in_specs=[
@@ -125,7 +125,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((block_q, 1), jnp.float32),     # running max
             pltpu.VMEM((block_q, 1), jnp.float32),     # running sum
             pltpu.VMEM((block_q, D), jnp.float32),     # output accumulator
-        ],
-        interpret=interpret,
-    )(qs, ks, vs)
+        ]),
+        qs, ks, vs, interpret=interpret)
     return out.reshape(B, H, nq * block_q, D)[:, :, :S]

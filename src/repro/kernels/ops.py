@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode; on TPU they
-compile to Mosaic.  ``use_pallas_attention()`` lets the model stack swap the
-pure-jnp chunked attention for the kernel on real hardware.
+Lowered for the CPU the kernels run in interpret mode; lowered for a TPU
+they compile to Mosaic (``repro.kernels.lowered_for_platform``).
+``use_pallas_attention()`` lets the model stack swap the pure-jnp chunked
+attention for the kernel on real hardware.
 """
 from __future__ import annotations
 

@@ -13,11 +13,15 @@ identity oracle.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import lowered_for_platform
 
 
 def _copy_kernel(src_ref, dst_ref):
@@ -27,8 +31,6 @@ def _copy_kernel(src_ref, dst_ref):
 def rbm_copy(x: jax.Array, *, tile_rows: int = 256, lanes: int = 128,
              interpret: Optional[bool] = None) -> jax.Array:
     """Copy ``x`` (any shape) through VMEM tiles of (tile_rows, lanes)."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
     flat = x.reshape(-1)
     n = flat.size
     per_tile = tile_rows * lanes
@@ -38,14 +40,14 @@ def rbm_copy(x: jax.Array, *, tile_rows: int = 256, lanes: int = 128,
         flat = jnp.pad(flat, (0, pad))
     tiled = flat.reshape(n_tiles * tile_rows, lanes)
 
-    out = pl.pallas_call(
+    out = lowered_for_platform(partial(
+        pl.pallas_call,
         _copy_kernel,
         grid=(n_tiles,),
         in_specs=[pl.BlockSpec((tile_rows, lanes), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile_rows, lanes), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(tiled.shape, x.dtype),
-        interpret=interpret,
-    )(tiled)
+        out_shape=jax.ShapeDtypeStruct(tiled.shape, x.dtype)),
+        tiled, interpret=interpret)
     return out.reshape(-1)[:n].reshape(x.shape)
 
 
@@ -64,13 +66,11 @@ def villa_gather(pages: jax.Array, table: jax.Array, *,
     DMA for page j+1 while page j is being written (LIP again) — this is the
     VILLA fast-tier read path.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    from jax.experimental.pallas import tpu as pltpu
     N, P, d = pages.shape
     n_out = table.shape[0]
 
-    out = pl.pallas_call(
+    return lowered_for_platform(partial(
+        pl.pallas_call,
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -78,10 +78,8 @@ def villa_gather(pages: jax.Array, table: jax.Array, *,
             in_specs=[pl.BlockSpec((1, P, d), lambda j, table: (table[j], 0, 0))],
             out_specs=pl.BlockSpec((1, P, d), lambda j, table: (j, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_out, P, d), pages.dtype),
-        interpret=interpret,
-    )(table.astype(jnp.int32), pages)
-    return out
+        out_shape=jax.ShapeDtypeStruct((n_out, P, d), pages.dtype)),
+        table.astype(jnp.int32), pages, interpret=interpret)
 
 
 def _scatter_kernel(table_ref, pages_ref, upd_ref, out_ref):
@@ -101,13 +99,11 @@ def villa_scatter(pages: jax.Array, table: jax.Array, updates: jax.Array, *,
     output (the donated row buffer), so cost is O(touched pages), not O(N).
     Duplicate table entries resolve in grid order (last write wins).
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    from jax.experimental.pallas import tpu as pltpu
     N, P, d = pages.shape
     n_upd = updates.shape[0]
 
-    return pl.pallas_call(
+    return lowered_for_platform(partial(
+        pl.pallas_call,
         _scatter_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -117,6 +113,5 @@ def villa_scatter(pages: jax.Array, table: jax.Array, updates: jax.Array, *,
             out_specs=pl.BlockSpec((1, P, d), lambda j, table: (table[j], 0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((N, P, d), pages.dtype),
-        input_output_aliases={1: 0},    # pages buffer IS the output
-        interpret=interpret,
-    )(table.astype(jnp.int32), pages, updates)
+        input_output_aliases={1: 0}),   # pages buffer IS the output
+        table.astype(jnp.int32), pages, updates, interpret=interpret)

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax import: device count locks at first init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell:
@@ -17,10 +13,16 @@ Usage:
   python -m repro.launch.dryrun --all            # every applicable cell
 Variants (hillclimbing levers): --no-fsdp --sp --cache-dtype int8
   --capacity-factor F --moe-groups N --no-remat --variant NAME
+
+The dry-run is a host-device rehearsal: ``main`` pins JAX to the CPU with
+512 forced host devices before JAX initializes a backend, and every cell
+child inherits that environment, so no process of it touches an
+accelerator.
 """
 import argparse
 import dataclasses
 import json
+import os
 import subprocess
 import sys
 import time
@@ -152,6 +154,10 @@ def _parser():
 
 
 def main() -> None:
+    # MUST precede JAX's backend initialization: the device count and the
+    # platform lock at first use.  Children inherit both.
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     args = _parser().parse_args()
     os.makedirs(args.out_dir, exist_ok=True)
 

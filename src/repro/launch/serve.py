@@ -35,6 +35,7 @@ import numpy as np
 
 from repro import sched
 from repro.configs import get_config, get_reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import lm
 from repro.serve.cluster import Cluster
 from repro.serve.engine import Engine, Request
@@ -175,6 +176,7 @@ def main(argv=None) -> dict:
                     f"{args.fork_prefix} + --max-new {args.max_new}")
     policy = args.policy or ("cost_aware_cluster" if args.replicas > 1
                              else "cost_aware")
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     params = lm.init_lm(cfg, jax.random.key(args.seed))
@@ -258,9 +260,22 @@ def main(argv=None) -> dict:
             engine.fork_many(fork_template_uid, uids,
                              seed_tokens=[fork_seeds[u] for u in uids])
 
-    t0 = time.time()
-    summary = s.run()
-    dt = time.time() - t0
+    # programs compile at their first call inside the run: JAX's compile
+    # events split the run's wall time into compile and serve seconds
+    compile_s = 0.0
+
+    def on_event(event: str, secs: float, **_) -> None:
+        nonlocal compile_s
+        if event.startswith("/jax/core/compile/"):
+            compile_s += secs
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        t0 = time.time()
+        summary = s.run()
+        dt = time.time() - t0
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
     eng_stats = eng.stats
 
     out = {
@@ -275,6 +290,9 @@ def main(argv=None) -> dict:
         "tokens_per_s": round(eng_stats["decoded_tokens"] / max(dt, 1e-9),
                               1),
         "seconds": round(dt, 1),
+        "compile_s": round(compile_s, 3),
+        "serve_s": round(dt - compile_s, 3),
+        "verify_failed": eng.verify_failure_count(),
     }
     if args.fork_prefix is not None:
         out["fork"] = {
@@ -291,7 +309,6 @@ def main(argv=None) -> dict:
         out["migrated_bytes"] = eng_stats["migrated_bytes"]
     if injector is not None:
         out["fault_ledger"] = injector.summary()
-        out["verify_failed"] = eng.verify_failure_count()
         out["at_rest_corrupt"] = int(eng.scrub())
     if tracer is not None:
         from repro import movement as MV

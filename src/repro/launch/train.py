@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.checkpoint import manager as ckpt
 from repro.configs import get_config, get_reduced
 from repro.data.pipeline import DataConfig, batch_at
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.optim.adamw import OptConfig
 from repro.train.step import (ParallelConfig, TrainState, init_train_state,
@@ -43,6 +44,7 @@ def main(argv=None) -> dict:
     p.add_argument("--grad-compress", action="store_true")
     p.add_argument("--log-every", type=int, default=10)
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     mesh = make_local_mesh(args.data, args.model)

@@ -458,6 +458,18 @@ def decode_step_batched(cfg: ModelConfig, params: Dict, cache: Dict,
     next_tokens is -1 for inactive slots, whose cache rows are left bit-exact
     (a suspended slot cannot be corrupted by a stale in-flight row).
     """
+    logits, new_cache = decode_logits_batched(cfg, params, cache, tokens,
+                                              pos, active, moe_groups)
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return jnp.where(active, nxt, -1), new_cache
+
+
+def decode_logits_batched(cfg: ModelConfig, params: Dict, cache: Dict,
+                          tokens: jax.Array, pos: jax.Array,
+                          active: jax.Array,
+                          moe_groups: int = 0) -> Tuple[jax.Array, Dict]:
+    """The model half of :func:`decode_step_batched`, before sampling:
+    returns (logits (B, V), new_cache) with the same arguments."""
     B = tokens.shape[0]
     pos = jnp.asarray(pos, jnp.int32)
     positions = pos[:, None]                                 # (B, 1)
@@ -474,13 +486,10 @@ def decode_step_batched(cfg: ModelConfig, params: Dict, cache: Dict,
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed(params["embed"] if cfg.tie_embeddings else params["head"],
                      x, tied=cfg.tie_embeddings)
-    nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-    nxt = jnp.where(active, nxt, -1)
     # Cache leaves are (reps, batch, ...): inactive rows keep their old bits.
     keep = lambda o, n: jnp.where(
         active.reshape((1, B) + (1,) * (n.ndim - 2)), n, o)
-    new_cache = jax.tree.map(keep, cache, new_cache)
-    return nxt, new_cache
+    return logits[:, -1, :], jax.tree.map(keep, cache, new_cache)
 
 
 def prefill(cfg: ModelConfig, params: Dict, tokens: jax.Array,

@@ -8,7 +8,8 @@ possible adapter from a typed leg to the underlying movement engine:
                                  LIP double buffering, input/output aliasing)
   tile_copy                  ->  Pallas rbm_copy (HBM->HBM through VMEM)
   hop_chain                  ->  ppermute hop chains over a mesh axis
-                                 (rbm.rbm_hop shift / rbm.lisa_copy chain)
+                                 (rbm.rbm_hop shift / rbm.lisa_copy chain),
+                                 or a cross-device transfer (``device``)
   host_stage                 ->  device_get / device_put across the channel
 
 The VILLA tier legs (``tier_read`` / ``tier_write``) are registered by
@@ -31,12 +32,14 @@ from repro.movement.registry import Env, register_backend
 @register_backend("pack_pages")
 def _pack_pages(leg: PackLeg, env: Env) -> Env:
     # Plural env keys declare a wave (see _unpack_pages): a fused suspend
-    # wave packs every slot in one vmapped dispatch.
+    # wave packs every slot in one dispatch, one slot after another — a
+    # vmapped pack would widen every slot's bytes at once (on TPU the byte
+    # split runs in uint32), which at published widths outgrows the chip.
     env = dict(env)
     if leg.batch > 1 or "slots" in env:
-        env["data"] = jax.vmap(
-            lambda s: paging.pack_slot(leg.page_spec, env["cache"], s))(
-                env["slots"])
+        env["data"] = jax.lax.map(
+            lambda s: paging.pack_slot(leg.page_spec, env["cache"], s),
+            env["slots"])
     else:
         env["data"] = paging.pack_slot(leg.page_spec, env["cache"],
                                        env["slot"])
@@ -104,13 +107,13 @@ def _tile_copy(leg: TileCopyLeg, env: Env) -> Env:
 @register_backend("hop_chain")
 def _hop_chain(leg: HopChainLeg, env: Env) -> Env:
     env = dict(env)
-    if env.get("local_fabric"):
-        # Single-process replica fleet (serve/cluster.py): the replica pools
-        # share one address space, so the payload the gather leg staged is
-        # already reachable by the scatter leg — the hop chain contributes
-        # the PRICED mesh route (the plan's cost is the ICI hop model) and
-        # is an identity on the bytes here.  On a real mesh the same leg
-        # executes the ppermute chain below (pinned by the shard_map tests).
+    if "device" in env:
+        # Single-process replica fleet (serve/cluster.py), one replica per
+        # device: the leg is the cross-device transfer of the staged pages
+        # to the destination replica's device, executed between the route's
+        # jitted gather and scatter.  Under shard_map the same leg runs the
+        # ppermute chain below (pinned by the forced-host mesh tests).
+        env["data"] = jax.device_put(env["data"], env["device"])
         return env
     if leg.src is None or leg.dst is None:
         env["data"] = rbm.rbm_hop(env["data"], leg.axis, leg.step)

@@ -23,6 +23,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Tuple
 
+import jax
+
 from repro.movement.plan import Leg, MovementPlan
 
 Env = Dict[str, Any]
@@ -122,11 +124,7 @@ def set_tracer(tracer: Any) -> None:
 
 
 def _tracing_clean() -> bool:
-    try:
-        from jax.core import trace_state_clean
-        return trace_state_clean()
-    except ImportError:                          # pragma: no cover - version
-        return False
+    return jax.core.trace_ctx.is_top_level()
 
 
 def execute(plan: MovementPlan, env: Env | None = None, **operands) -> Env:

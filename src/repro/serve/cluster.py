@@ -31,16 +31,17 @@ cluster exposes an engine-shaped surface over *global* slot ids
 (:class:`repro.sched.scheduler.ClusterScheduler`) drives it exactly like an
 engine, plus the placement axis.
 
-The cluster is single-process: replicas are separate device buffers in one
-address space, so the hop-chain leg of a migration plan is executed as the
-priced route (``local_fabric`` mode) while the gather/scatter legs carry
-the bytes.  The same plan executes a real ``ppermute`` chain under
-``shard_map`` on a multi-device mesh (pinned by tests/test_cluster.py's
-forced-host 4-device test).
+The cluster is single-process and places replica ``i`` on device
+``i mod len(jax.devices())``: on a four-chip host each replica's params,
+cache and session store live on their own chip.  A migration executes its
+plan leg by leg along the route: the page gather on the source replica's
+device, the hop-chain leg as the cross-device transfer of the staged pages,
+the page scatter on the destination's device.  Under ``shard_map`` the same
+plan's hop-chain leg runs as a ``ppermute`` chain instead (pinned by
+tests/test_cluster.py's forced-host 4-device test).
 """
 from __future__ import annotations
 
-import warnings
 from functools import partial
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -55,7 +56,8 @@ from repro.core.dram.villa import VillaConfig
 from repro.core.lisa.topology import MeshTopology
 from repro.faults.inject import install_fault_backends
 from repro.faults.spec import NULL_FAULT, FaultInjector
-from repro.serve.engine import Engine, EngineFull, Request, UnknownSession
+from repro.serve.engine import (Engine, EngineFull, Request, UnknownSession,
+                                _quiet)
 
 
 class Cluster:
@@ -88,10 +90,11 @@ class Cluster:
             raise ValueError(f"topology size {self.topo.size} != "
                              f"n_replicas {n_replicas}")
         self.replicas: List[Engine] = []
+        devices = jax.devices()
         for r in range(n_replicas):
             eng = Engine(cfg, params, slots=slots, max_len=max_len,
                          n_sessions=n_sessions, villa=villa, spec=spec,
-                         replica_id=r)
+                         replica_id=r, device=devices[r % len(devices)])
             if self.replicas:
                 # one compile serves the whole fleet
                 eng.adopt_jits(self.replicas[0])
@@ -114,7 +117,8 @@ class Cluster:
                               "retry_backoff_ns": 0.0,
                               "fork_materializations": 0}
         self._route_plans: Dict[Tuple, MV.MovementPlan] = {}
-        self._migrate_exec = None       # built lazily (n_replicas > 1 only)
+        # the route executor's jitted legs, built on the first migration
+        self._migrate_gather = self._migrate_exec = None
         self._fault_events: List[Dict[str, object]] = []
         self.tracer = None              # set by attach_tracer (repro.obs)
 
@@ -191,9 +195,7 @@ class Cluster:
     def compile_counts(self) -> Dict[str, int]:
         out = self.replicas[0].compile_counts()     # jits are fleet-shared
         fn = self._migrate_exec
-        out["migrate"] = (fn._cache_size()
-                         if fn is not None and hasattr(fn, "_cache_size")
-                         else (0 if fn is None else -1))
+        out["migrate"] = 0 if fn is None else fn._cache_size()
         return out
 
     # ---- decode ------------------------------------------------------------
@@ -322,31 +324,64 @@ class Cluster:
         c = self.migration_plan(src, dst).cost
         return c.ns_lisa if mechanism == "lisa" else c.ns_memcpy
 
-    def _build_migrate_exec(self):
-        """The jitted route executor, shared by every route: gather the
-        sessions' pages out of the source pool, scatter them into the
-        destination pool (donated).  The hop-chain leg between them is the
-        priced mesh route (identity in single-process ``local_fabric``
-        mode); routes differ only in pricing, so ONE compilation per wave
-        width serves every route."""
-        exec_plan = self.migration_plan(0, 1 % self.n_replicas)
+    def _build_migrate_exec(self) -> None:
+        """The jitted legs of the route executor, shared by every route
+        (routes differ only in pricing, so ONE compilation per wave width
+        and device serves them all): the gather leg reads the sessions'
+        pages out of the source pool, the scatter leg writes them into the
+        donated destination pool.  :meth:`_stage` runs the hop-chain leg
+        between them."""
+        route = self.migration_plan(0, 1 % self.n_replicas)
+        gather, self._hop_plan, scatter = (route._replace(legs=(leg,))
+                                           for leg in route.legs)
         P, d = self.page_spec.page_rows, self.page_spec.page_lanes
 
-        @partial(jax.jit, donate_argnums=(1,))
-        def body(src_slow, dst_slow, src_table, dst_table, fault):
+        @jax.jit
+        def gather_fn(src_slow, src_table):
+            return MV.execute(gather, src_pool=src_slow.reshape(-1, P, d),
+                              src_table=src_table)["data"]
+
+        @partial(jax.jit, donate_argnums=(0,))
+        def scatter_fn(dst_slow, dst_table, data, fault):
             # ``fault`` is the traced (mode, index, xor) chaos operand —
             # NULL_FAULT on clean runs — consumed by the fault-wrapped
-            # hop-chain backend when chaos mode installed the wrappers, and
+            # scatter backend when chaos mode installed the wrappers, and
             # simply unused otherwise: one compilation either way.
-            env = MV.execute(exec_plan,
-                             src_pool=src_slow.reshape(-1, P, d),
-                             src_table=src_table,
-                             dst_pool=dst_slow.reshape(-1, P, d),
-                             dst_table=dst_table, local_fabric=True,
-                             fault=fault)
+            env = MV.execute(scatter, dst_pool=dst_slow.reshape(-1, P, d),
+                             dst_table=dst_table, data=data, fault=fault)
             return env["dst_pool"].reshape(dst_slow.shape)
 
-        return body
+        self._migrate_gather, self._migrate_exec = gather_fn, scatter_fn
+
+    def _stage(self, s_eng: Engine, d_eng: Engine,
+               src_idx: Sequence[int]) -> Tuple[jax.Array, jax.Array]:
+        """A route's gather leg on the source replica's device, then its
+        hop-chain leg: the staged pages cross to the destination replica's
+        device, and the checksum sidecar rows travel with them.  Returns
+        ``(pages, sums)`` on the destination device."""
+        if self._migrate_exec is None:
+            self._build_migrate_exec()
+        pages = self._migrate_gather(s_eng.sessions.slow,
+                                     self._page_table(src_idx))
+        pages = MV.execute(self._hop_plan, data=pages,
+                           device=d_eng.device)["data"]
+        sums = jax.device_put(s_eng.session_sums[jnp.asarray(src_idx)],
+                              d_eng.device)
+        return pages, sums
+
+    def _land(self, dst_slow: jax.Array, dst_idx: Sequence[int],
+              pages: jax.Array, fault) -> jax.Array:
+        """A route's scatter leg on the destination device: returns the new
+        destination slow pool (``dst_slow`` is donated)."""
+        return _quiet(self._migrate_exec, dst_slow,
+                      self._page_table(dst_idx), pages, jnp.asarray(fault))
+
+    def _page_table(self, rows: Sequence[int]) -> np.ndarray:
+        """The flat-pool page table of whole store rows, in row order (a
+        host array: the leg's jit places it on the replica's device)."""
+        spp = self.page_spec.n_pages
+        return np.concatenate(
+            [r * spp + np.arange(spp, dtype=np.int32) for r in rows])
 
     def migrate(self, uid: int, dst: int) -> None:
         self.migrate_many([(uid, dst)])
@@ -355,10 +390,12 @@ class Cluster:
         """Migrate a burst of suspended sessions, each ``(uid, dst_replica)``.
 
         Sessions are grouped by (src, dst) route; each route executes as
-        ONE jitted page gather+scatter over a fused page table (the wave
-        idiom of ``suspend_many``/``resume_many``), priced by one hop-chain
-        plan of batch k.  Bit-exact and loss-free: uint8 pages plus the
-        host bookkeeping (position, seed token) move together."""
+        ONE jitted page gather on the source device, one cross-device hop
+        and ONE jitted page scatter on the destination device over a fused
+        page table (the wave idiom of ``suspend_many``/``resume_many``),
+        priced by one hop-chain plan of batch k.  Bit-exact and loss-free:
+        uint8 pages plus the host bookkeeping (position, seed token) move
+        together."""
         if not moves:
             return
         uids = [u for u, _ in moves]
@@ -377,12 +414,8 @@ class Cluster:
                 raise ValueError(f"uid {uid} already resides on replica "
                                  f"{dst}; migration needs a real route")
             routes.setdefault((src, dst), []).append(uid)
-        if self._migrate_exec is None:
-            self._migrate_exec = self._build_migrate_exec()
-
         spp = self.page_spec.n_pages
         page_bytes = self.page_spec.page_bytes
-        arange = np.arange(spp, dtype=np.int32)
         for (src, dst), route_uids in routes.items():
             s_eng, d_eng = self.replicas[src], self.replicas[dst]
             metas = [s_eng.session_meta(u) for u in route_uids]
@@ -390,29 +423,17 @@ class Cluster:
             dst_idx = [d_eng.adopt_session(u, p, t)
                        for u, (p, t) in zip(route_uids, metas)]
             self._invalidate_fast(d_eng, dst_idx)
-            src_table = jnp.asarray(
-                np.concatenate([i * spp + arange for i in src_idx]))
-            dst_table = jnp.asarray(
-                np.concatenate([i * spp + arange for i in dst_idx]))
+            pages, sums = self._stage(s_eng, d_eng, src_idx)
             k = len(route_uids)
-
-            def run_route(dst_slow, fault):
-                with warnings.catch_warnings():
-                    warnings.filterwarnings(
-                        "ignore",
-                        message="Some donated buffers were not usable")
-                    return self._migrate_exec(
-                        s_eng.sessions.slow, dst_slow, src_table, dst_table,
-                        jnp.asarray(fault))
 
             inj = self.faults
             fault = (inj.draw_movement(k * spp * page_bytes, k * spp)
                      if inj is not None else NULL_FAULT)
-            new_slow = run_route(d_eng.sessions.slow, fault)
+            new_slow = self._land(d_eng.sessions.slow, dst_idx, pages, fault)
             if inj is not None and int(fault[0]) != 0:
                 # The injector KNOWS it corrupted this wave (host-
                 # deterministic — no mid-loop device read needed): retry the
-                # whole route from the intact source pages, each retry a
+                # landing from the intact staged pages, each retry a
                 # fresh draw, bounded by max_retries with exponential
                 # backoff.  The retries and backoff are real latency —
                 # the scheduler prices them into the virtual clock via
@@ -425,7 +446,7 @@ class Cluster:
                     inj.counters["retries"] += 1
                     backoff_total += inj.backoff_ns(retries)
                     fault = inj.draw_movement(k * spp * page_bytes, k * spp)
-                    new_slow = run_route(new_slow, fault)
+                    new_slow = self._land(new_slow, dst_idx, pages, fault)
                 corrupt_uid = None
                 if int(fault[0]) != 0:          # landed corrupt (no/lost
                     mode, index = int(fault[0]), int(fault[1])  # recovery)
@@ -450,8 +471,7 @@ class Cluster:
             # suspend time on the SOURCE, so corruption in flight is exactly
             # what the destination's resume-time verify will catch
             d_eng.session_sums = d_eng.session_sums.at[
-                jnp.asarray(dst_idx)].set(
-                    s_eng.session_sums[jnp.asarray(src_idx)])
+                jnp.asarray(dst_idx)].set(sums)
             for uid in route_uids:
                 self.residence[uid] = dst
             cost = self.migration_plan(src, dst, k).cost
@@ -475,7 +495,7 @@ class Cluster:
         Different replica: the alias cannot span pools (refcounts are
         per-replica), so the fork MATERIALIZES — the parent's snapshot row
         is copied over the existing priced migration route (page gather ->
-        mesh hop chain -> page scatter, ONE dispatch) into an exclusive row
+        mesh hop chain -> page scatter) into an exclusive row
         on the destination; the parent and its refcounts are untouched.
         The copy is drawn with NULL_FAULT deliberately: materialization is
         a fresh admission, not an in-flight session move — chaos targets
@@ -502,21 +522,10 @@ class Cluster:
         seed = tok if seed_token is None else int(seed_token)
         dst_idx = d_eng.adopt_session(child_uid, pos, seed)
         self._invalidate_fast(d_eng, [dst_idx])
-        if self._migrate_exec is None:
-            self._migrate_exec = self._build_migrate_exec()
-        spp = self.page_spec.n_pages
-        arange = np.arange(spp, dtype=np.int32)
-        with warnings.catch_warnings():
-            warnings.filterwarnings(
-                "ignore", message="Some donated buffers were not usable")
-            new_slow = self._migrate_exec(
-                s_eng.sessions.slow, d_eng.sessions.slow,
-                jnp.asarray(src_phys * spp + arange),
-                jnp.asarray(dst_idx * spp + arange),
-                jnp.asarray(NULL_FAULT))
-        d_eng.sessions = d_eng.sessions._replace(slow=new_slow)
-        d_eng.session_sums = d_eng.session_sums.at[dst_idx].set(
-            s_eng.session_sums[src_phys])
+        pages, sums = self._stage(s_eng, d_eng, [src_phys])
+        d_eng.sessions = d_eng.sessions._replace(slow=self._land(
+            d_eng.sessions.slow, [dst_idx], pages, NULL_FAULT))
+        d_eng.session_sums = d_eng.session_sums.at[dst_idx].set(sums[0])
         self.residence[child_uid] = dst
         cost = self._fork_route_plan(src, dst).cost
         self.cluster_stats["fork_materializations"] += 1

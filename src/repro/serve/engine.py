@@ -51,10 +51,13 @@ POS_SENTINEL = 2**30     # matches the cache init sentinel in models/lm.py
 
 
 def _quiet(fn, *args):
-    """Run one donated-buffer dispatch without the CPU backend's 'donated
-    buffers were not usable' warning (CPU XLA cannot honor donation; the
-    hint is still correct on TPU).  Scoped per call so other code keeps the
-    diagnostic for its own donation mistakes."""
+    """Run one donated-buffer dispatch, without the 'donated buffers were
+    not usable' warning on the CPU backend only: CPU XLA cannot honor
+    donation.  On every other backend a dropped donation is a real copy of
+    the buffer, so its warning stays visible.  Scoped per call so other code
+    keeps the diagnostic for its own donation mistakes."""
+    if jax.default_backend() != "cpu":
+        return fn(*args)
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
@@ -85,13 +88,99 @@ class Request:
     slo_ns: float = float("inf")
 
 
+def prefill_insert(cfg: ModelConfig, max_len: int, params, cache, tokens,
+                   positions, true_len, slot):
+    """Prefill one request and insert its KV into ``slot``: the body of
+    :class:`Engine`'s prefill dispatch, returns (next_token scalar, cache).
+    ``tokens`` may be right-padded to a bucket length; pads carry sentinel
+    positions."""
+    cache1 = lm.init_cache(cfg, 1, max_len=max_len)
+    logits, cache1 = lm.prefill(cfg, params, tokens, cache1,
+                                positions=positions)
+    nxt = jnp.argmax(logits[0, true_len - 1]).astype(jnp.int32)
+    cache = jax.tree.map(
+        lambda full, p: jax.lax.dynamic_update_slice_in_dim(
+            full, p.astype(full.dtype), slot, axis=1), cache, cache1)
+    return nxt, cache
+
+
+def default_villa(n_sessions: int) -> VillaConfig:
+    """The session store's VILLA tiering when :class:`Engine` is given
+    none: a fast tier of a quarter of the sessions (at least 2)."""
+    return VillaConfig(n_counters=n_sessions, n_hot=max(n_sessions // 4, 2),
+                       n_slots=max(n_sessions // 4, 2), epoch_len=8)
+
+
+def session_plans(page_spec: PS.PageSpec, villa: VillaConfig,
+                  spec: DramSpec):
+    """(suspend plan, resume plan): one session's snapshot moving from the
+    compute tier into the VILLA slow tier and back, lowered against
+    ``spec``."""
+    layout = MV.Layout.pages(page_spec)
+    return tuple(MV.plan(MV.Transfer(MV.Tier(a), MV.Tier(b), layout,
+                                     policy=villa), spec)
+                 for a, b in (("compute", "slow"), ("slow", "compute")))
+
+
+# ---- jitted bodies of Engine (traced slot/store indices; donated buffers) --
+def _suspend_body(plan, cache, store, sums, slot, idx):
+    env = MV.execute(plan, cache=cache, slot=slot, store=store, item=idx)
+    # the pack leg emitted per-page checksums; persist them in the sidecar
+    # row for this store index (donated: updated in place)
+    return env["store"], sums.at[idx].set(env["sums"])
+
+
+def _resume_body(plan, cache, store, sums, failed, slot, idx):
+    env = MV.execute(plan, cache=cache, store=store, slot=slot, item=idx,
+                     sums=sums[idx])
+    return env["cache"], env["store"], failed + env["verify_fail"]
+
+
+def _fused_wave(wave_plans: Dict[tuple, MV.MovementPlan],
+                single: MV.MovementPlan, k: int) -> MV.MovementPlan:
+    """A whole wave as ONE fused plan (k identical transfers -> one
+    pack / batched tier access / scanned unpack: one dispatch), cached in
+    ``wave_plans``."""
+    key = (id(single), k)
+    if key not in wave_plans:
+        wave_plans[key] = MV.fuse([single] * k)
+    return wave_plans[key]
+
+
+def _suspend_many_body(plan, wave_plans, cache, store, sums, slots, idxs):
+    env = MV.execute(_fused_wave(wave_plans, plan, slots.shape[0]),
+                     cache=cache, slots=slots, store=store, items=idxs)
+    return env["store"], sums.at[idxs].set(env["sums"])
+
+
+def _resume_many_body(plan, wave_plans, cache, store, sums, failed, slots,
+                      idxs):
+    env = MV.execute(_fused_wave(wave_plans, plan, slots.shape[0]),
+                     cache=cache, store=store, slots=slots, items=idxs,
+                     sums=sums[idxs])
+    return env["cache"], env["store"], failed + env["verify_fail"]
+
+
+def _clone_body(store, sums, src, dst):
+    """Shared-row demotion body: clone slow row src -> dst (pages AND
+    checksum sidecar) in one dispatch; the fork table repoints the aliases
+    right after."""
+    return VC.clone_item(store, src, dst), sums.at[dst].set(sums[src])
+
+
 class Engine:
     def __init__(self, cfg: ModelConfig, params, *, slots: int = 4,
                  max_len: int = 128, n_sessions: int = 64,
                  villa: Optional[VillaConfig] = None,
-                 spec: DramSpec = DDR3_1600, replica_id: int = 0):
+                 spec: DramSpec = DDR3_1600, replica_id: int = 0,
+                 device: Optional[jax.Device] = None):
+        # every buffer this engine owns is committed to ONE device (default:
+        # the first), so its dispatches run there and a replica fleet
+        # (serve/cluster.py) spreads one engine per chip
+        self.device = device if device is not None else jax.devices()[0]
+        put = partial(jax.device_put, device=self.device)
         self.cfg = cfg
-        self.params = params
+        self.params = put(params)
         self.spec = spec
         self.slots = slots
         self.max_len = max_len
@@ -103,7 +192,8 @@ class Engine:
         self.active: Dict[int, Request] = {}        # slot -> request
         self.pos = np.zeros(slots, np.int32)
 
-        self.cache = lm.init_cache(cfg, slots, max_len=max_len)
+        with jax.default_device(self.device):
+            self.cache = put(lm.init_cache(cfg, slots, max_len=max_len))
         # ONE jitted decode for the whole ragged batch; the cache buffer is
         # donated — XLA writes the new KV in place instead of copying it.
         self._decode = jax.jit(partial(lm.decode_step_batched, cfg),
@@ -119,16 +209,16 @@ class Engine:
         self._can_bucket = (not cfg.encdec and not cfg.mrope and
                             all(k in ("attn_full", "mla")
                                 for k in cfg.layer_kinds()))
-        self._prefill = jax.jit(self._prefill_insert, donate_argnums=(1,))
+        self._prefill = jax.jit(partial(prefill_insert, cfg, max_len),
+                                donate_argnums=(1,))
 
         # Session store: suspended KV snapshots as dtype-preserving uint8
         # pages in a VILLA tiered store (movement via the RBM page kernels).
         self.page_spec = PS.PageSpec.for_cache(self.cache)
-        self.villa_cfg = villa or VillaConfig(
-            n_counters=n_sessions, n_hot=max(n_sessions // 4, 2),
-            n_slots=max(n_sessions // 4, 2), epoch_len=8)
-        self.sessions = PS.make_session_store(self.page_spec, n_sessions,
-                                              self.villa_cfg)
+        self.villa_cfg = villa or default_villa(n_sessions)
+        with jax.default_device(self.device):
+            self.sessions = put(PS.make_session_store(
+                self.page_spec, n_sessions, self.villa_cfg))
         self.session_pos: Dict[int, int] = {}       # uid -> next position
         self.session_tok: Dict[int, int] = {}       # uid -> last emitted token
         self.store_uid: Dict[int, int] = {}         # phys row -> owner uid
@@ -143,32 +233,37 @@ class Engine:
         # resume.  ``verify_failed`` accumulates ON DEVICE — the verdict
         # rides whichever sync a caller already performs (bench/test
         # surface), never adding one to the tick loop.
-        self.session_sums = jnp.zeros(
-            (n_sessions, self.page_spec.n_pages), jnp.uint32)
-        self.verify_failed = jnp.zeros((), jnp.int32)
+        self.session_sums = put(jnp.zeros(
+            (n_sessions, self.page_spec.n_pages), jnp.uint32))
+        self.verify_failed = put(jnp.zeros((), jnp.int32))
         self.fast_degraded = False
-        self._suspend = jax.jit(self._suspend_fn, donate_argnums=(1, 2))
-        self._suspend_many = jax.jit(self._suspend_many_fn,
-                                     donate_argnums=(1, 2))
-        self._resume = jax.jit(self._resume_fn, donate_argnums=(0, 1, 3))
-        self._resume_many = jax.jit(self._resume_many_fn,
-                                    donate_argnums=(0, 1, 3))
-        # shared-row demotion: device-clone one slow row (store + checksum
-        # sidecar) so a shared snapshot can yield its index without being
-        # destroyed
-        self._clone = jax.jit(self._clone_fn, donate_argnums=(0, 1))
 
         # Every suspend/resume is a planned movement between the compute
         # tier and the VILLA slow tier, lowered ONCE here against the spec;
         # the jitted bodies execute the plans, and each call charges its
         # plan's modeled MovementCost (lisa hop chain vs channel memcpy).
-        _layout = MV.Layout.pages(self.page_spec)
-        self.plan_suspend = MV.plan(MV.Transfer(
-            MV.Tier("compute"), MV.Tier("slow"), _layout,
-            policy=self.villa_cfg), spec)
-        self.plan_resume = MV.plan(MV.Transfer(
-            MV.Tier("slow"), MV.Tier("compute"), _layout,
-            policy=self.villa_cfg), spec)
+        self.plan_suspend, self.plan_resume = session_plans(
+            self.page_spec, self.villa_cfg, spec)
+        self._wave_plans: Dict[tuple, MV.MovementPlan] = {}
+        # the jitted bodies close over the plans and the wave-plan cache,
+        # never over the engine: a jit that held a bound method would tie
+        # the engine (and, through adopt_jits, every replica sharing it) in
+        # a reference cycle, keeping its device buffers alive until the
+        # cyclic collector happens to run
+        self._suspend = jax.jit(partial(_suspend_body, self.plan_suspend),
+                                donate_argnums=(1, 2))
+        self._suspend_many = jax.jit(
+            partial(_suspend_many_body, self.plan_suspend, self._wave_plans),
+            donate_argnums=(1, 2))
+        self._resume = jax.jit(partial(_resume_body, self.plan_resume),
+                               donate_argnums=(0, 1, 3))
+        self._resume_many = jax.jit(
+            partial(_resume_many_body, self.plan_resume, self._wave_plans),
+            donate_argnums=(0, 1, 3))
+        # shared-row demotion: device-clone one slow row (store + checksum
+        # sidecar) so a shared snapshot can yield its index without being
+        # destroyed
+        self._clone = jax.jit(_clone_body, donate_argnums=(0, 1))
         # Fork fast path: a same-replica ``fork``-kind transfer lowers to
         # ONE page_alias leg — host bookkeeping priced as RowClone FPM on
         # the lisa arm vs the per-session copy it avoids on the memcpy arm
@@ -176,9 +271,9 @@ class Engine:
         # row's real bytes within the pool, priced under the same alias
         # mechanism (an in-subarray RowClone of one row).
         self.plan_fork = MV.plan(MV.Transfer(
-            MV.Tier("slow"), MV.Tier("slow"), _layout, kind="fork"), spec)
+            MV.Tier("slow"), MV.Tier("slow"), MV.Layout.pages(self.page_spec),
+            kind="fork"), spec)
         self.plan_demote = self.plan_fork
-        self._wave_plans: Dict[tuple, MV.MovementPlan] = {}
         self.snapshot_bytes = self.page_spec.total_bytes
         self.stats = {"decoded_tokens": 0, "prefills": 0, "suspends": 0,
                       "resumes": 0,
@@ -201,59 +296,9 @@ class Engine:
         self.tracer = tracer
         self.trace_lane = lane if lane is not None else 0
 
-    # ---- jitted bodies (traced slot/store indices; donated buffers) -------
-    def _prefill_insert(self, params, cache, tokens, positions, true_len,
-                        slot):
-        """Prefill one request and insert its KV into ``slot``: one dispatch,
-        returns (next_token scalar, cache).  ``tokens`` may be right-padded
-        to a bucket length; pads carry sentinel positions."""
-        cache1 = lm.init_cache(self.cfg, 1, max_len=self.max_len)
-        logits, cache1 = lm.prefill(self.cfg, params, tokens, cache1,
-                                    positions=positions)
-        nxt = jnp.argmax(logits[0, true_len - 1]).astype(jnp.int32)
-        cache = jax.tree.map(
-            lambda full, p: jax.lax.dynamic_update_slice_in_dim(
-                full, p.astype(full.dtype), slot, axis=1), cache, cache1)
-        return nxt, cache
-
-    def _suspend_fn(self, cache, store, sums, slot, idx):
-        env = MV.execute(self.plan_suspend, cache=cache, slot=slot,
-                         store=store, item=idx)
-        # the pack leg emitted per-page checksums; persist them in the
-        # sidecar row for this store index (donated: updated in place)
-        return env["store"], sums.at[idx].set(env["sums"])
-
-    def _resume_fn(self, cache, store, sums, failed, slot, idx):
-        env = MV.execute(self.plan_resume, cache=cache, store=store,
-                         slot=slot, item=idx, sums=sums[idx])
-        return env["cache"], env["store"], failed + env["verify_fail"]
-
     def _wave_plan(self, single: MV.MovementPlan, k: int) -> MV.MovementPlan:
-        """A whole wave as ONE fused plan (k identical transfers -> one
-        vmapped pack / batched tier access / scanned unpack: one
-        dispatch)."""
-        key = (id(single), k)
-        if key not in self._wave_plans:
-            self._wave_plans[key] = MV.fuse([single] * k)
-        return self._wave_plans[key]
-
-    def _suspend_many_fn(self, cache, store, sums, slots, idxs):
-        env = MV.execute(self._wave_plan(self.plan_suspend, slots.shape[0]),
-                         cache=cache, slots=slots, store=store, items=idxs)
-        return env["store"], sums.at[idxs].set(env["sums"])
-
-    def _resume_many_fn(self, cache, store, sums, failed, slots, idxs):
-        env = MV.execute(self._wave_plan(self.plan_resume, slots.shape[0]),
-                         cache=cache, store=store, slots=slots, items=idxs,
-                         sums=sums[idxs])
-        return env["cache"], env["store"], failed + env["verify_fail"]
-
-    def _clone_fn(self, store, sums, src, dst):
-        """Shared-row demotion body: clone slow row src -> dst (pages AND
-        checksum sidecar) in one dispatch; the fork table repoints the
-        aliases right after."""
-        return (VC.clone_item(store, src, dst),
-                sums.at[dst].set(sums[src]))
+        """A whole wave as ONE fused plan (see :func:`_fused_wave`)."""
+        return _fused_wave(self._wave_plans, single, k)
 
     # ---- scheduling -------------------------------------------------------
     def free_slots(self) -> List[int]:
@@ -868,14 +913,10 @@ class Engine:
         return int(self.verify_failed)
 
     def compile_counts(self) -> Dict[str, int]:
-        """Jit-cache sizes of the hot-path entry points (compilations seen).
-        -1 when the jax build exposes no cache-size probe — asserters should
-        treat -1 as 'unknown', not as a regression."""
-        out = {}
-        for name, fn in [("decode", self._decode), ("prefill", self._prefill),
-                         ("suspend", self._suspend), ("resume", self._resume),
-                         ("suspend_many", self._suspend_many),
-                         ("resume_many", self._resume_many),
-                         ("clone", self._clone)]:
-            out[name] = fn._cache_size() if hasattr(fn, "_cache_size") else -1
-        return out
+        """Jit-cache sizes of the hot-path entry points (compilations
+        seen)."""
+        return {name: fn._cache_size() for name, fn in [
+            ("decode", self._decode), ("prefill", self._prefill),
+            ("suspend", self._suspend), ("resume", self._resume),
+            ("suspend_many", self._suspend_many),
+            ("resume_many", self._resume_many), ("clone", self._clone)]}

@@ -44,7 +44,7 @@ def gpipe(stage_fn: Callable, axis_name: str):
         n_micro = micro_in.shape[0]
         n_slots = n_stages + n_micro - 1
 
-        micro_in = jax.lax.pvary(micro_in, (axis_name,))
+        micro_in = jax.lax.pcast(micro_in, (axis_name,), to="varying")
         out_shape = jax.eval_shape(stage_fn, stage_params, micro_in[0])
         # Stage-to-stage hop as a movement plan: one neighbor-shift
         # hop-chain leg, planned once per activation shape at trace time.
@@ -52,7 +52,7 @@ def gpipe(stage_fn: Callable, axis_name: str):
             MV.Tier("stage", axis=axis_name), MV.Tier("stage", axis=axis_name),
             MV.Layout.dense(out_shape.shape, out_shape.dtype)))
         outputs = jnp.zeros((n_micro,) + out_shape.shape, out_shape.dtype)
-        outputs = jax.lax.pvary(outputs, (axis_name,))
+        outputs = jax.lax.pcast(outputs, (axis_name,), to="varying")
         carry_in = jnp.zeros_like(micro_in[0])
 
         def slot(t, state):

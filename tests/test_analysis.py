@@ -244,7 +244,7 @@ def test_default_targets_audit_clean():
     assert audit["findings"] == [], audit["findings"]
     names = {t["name"] for t in audit["targets"]}
     assert {"decode", "suspend", "suspend_many", "resume", "resume_many",
-            "migrate", "simulate_params"} <= names
+            "migrate_gather", "migrate", "simulate_params"} <= names
     assert any(n.startswith("prefill[") for n in names)
     for rec in audit["targets"]:
         assert rec["donated_leaves"] == rec["expected_donated_leaves"]
@@ -256,10 +256,10 @@ def test_default_targets_audit_clean():
 # ---------------------------------------------------------------------------
 
 def test_testlib_compile_count_contract():
-    counts = {"decode": 1, "resume_many": 2, "suspend": 0, "probe": -1}
+    counts = {"decode": 1, "resume_many": 2, "suspend": 0}
     TL.assert_compile_count(counts, "decode", 1)
     TL.assert_compile_count(counts, "resume_many", range(3))
-    TL.assert_compile_count(counts, "probe", 1)          # -1 == unknown
+    TL.assert_compile_count(counts, "suspend", 0)        # unused path
     TL.assert_compile_at_most(counts, "resume_many", 2)
     with pytest.raises(AssertionError, match="decode compiled 1x"):
         TL.assert_compile_count(counts, "decode", 2)

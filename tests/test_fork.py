@@ -580,7 +580,7 @@ def body(shard):
 
 out = np.asarray(jax.jit(jax.shard_map(
     body, mesh=mesh, in_specs=P("replica"), out_specs=P("replica"),
-    check_rep=False))(pool))
+    check_vma=False))(pool))
 want = np.asarray(pool).copy()
 want[DST][np.asarray(dst_table)] = want[SRC][np.asarray(src_table)]
 assert (out == want).all(), "materialized fork pages did not land bit-exactly"

@@ -6,7 +6,9 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.core.lisa import rbm, compression as C
 
-mesh = jax.make_mesh((8,), ("x",))
+# Auto axes: the oracles below index the sharded results eagerly, which
+# Explicit-axis meshes (make_mesh's default) refuse
+mesh = jax.make_mesh((8,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.arange(8 * 4, dtype=jnp.float32).reshape(8, 4)
 
 def smap(f, in_specs=P("x"), out_specs=P("x")):
