@@ -516,34 +516,6 @@ def bench_movement(out_path="BENCH_movement.json"):
         f"modeled_advantage={bench['modeled_advantage']}x")
 
 
-def _roofline_attribution(path="ROOFLINE_REPORT.json"):
-    """Span-name -> roofline attrs from the committed live report (empty
-    dict when absent/unreadable): traced decode/prefill spans then carry
-    the dominant HLO kernel and its byte/flop totals, tying the virtual
-    timeline back to the lowered IR."""
-    if not os.path.exists(path):
-        return {}
-    try:
-        with open(path) as f:
-            rep = json.load(f)
-        entries = rep["entries"]
-    except (OSError, ValueError, KeyError, TypeError):
-        return {}
-
-    def attrs(e):
-        return {"hlo_dominant": e["dominant"],
-                "hlo_gflops": round(e["flops"] / 1e9, 4),
-                "hlo_gbytes": round(e["bytes"] / 1e9, 4)}
-
-    out = {}
-    if "decode" in entries:
-        out["decode"] = attrs(entries["decode"])
-    buckets = sorted(n for n in entries if n.startswith("prefill["))
-    if buckets:
-        out["prefill"] = attrs(entries[buckets[-1]])
-    return out
-
-
 def bench_sched(out_path="BENCH_sched.json"):
     """Scheduler A/B: ``fifo`` vs ``cost_aware`` serving the SAME offered
     load (identical arrival stream, engine geometry and virtual-clock
@@ -577,7 +549,6 @@ def bench_sched(out_path="BENCH_sched.json"):
             # schedule impact — the summary gains a "trace" rollup block
             from repro.obs import Tracer
             tracer = Tracer()
-            tracer.bind_attribution(_roofline_attribution())
         s = sched.Scheduler(eng, policy=pol, arrivals=arrivals,
                             tracer=tracer)
         t0 = time.perf_counter()

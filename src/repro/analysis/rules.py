@@ -15,8 +15,8 @@ Each rule guards one paper invariant (DESIGN.md Sec. 11 has the mapping):
   registry would bypass the Table-1 cost accounting (LISA's point is that
   the *mechanism* is priced, not assumed).
 * ``host-sync-in-hot-loop`` — the tick loop and wave dispatch never sync
-  the device beyond the one sanctioned transfer per step: a stray
-  ``.item()`` is a trip across the narrow channel mid-wave.
+  the device beyond the sanctioned reads (the step's tokens, the policy
+  tags): a stray ``.item()`` is a trip across the narrow channel mid-wave.
 * ``wallclock-in-virtual-clock`` — scheduling runs on the virtual clock;
   wall-clock reads or unseeded RNG would make the priced schedules (and the
   CI-gated BENCH numbers) nondeterministic.
@@ -187,7 +187,9 @@ class RawBackendRule(LintRule):
 class HostSyncRule(LintRule):
     """The serving hot path makes exactly ONE device→host transfer per
     decode step (``Engine.step_end``) plus the small sanctioned policy-tag
-    reads the scheduler's cost scoring consults between dispatches.  Any
+    reads the scheduler's cost scoring consults between dispatches (and
+    each prefill's first token, ``Engine.submit``; all three are counted in
+    ``Engine.stats``).  Any
     other sync idiom in tick-loop or wave-dispatch code is a trip across
     the narrow channel the architecture exists to avoid.  The sanctioned
     readers are structural allowlist entries HERE (reviewed with the rule),

@@ -72,6 +72,7 @@ def villa_gather(pages: jax.Array, table: jax.Array, *,
     return lowered_for_platform(partial(
         pl.pallas_call,
         _gather_kernel,
+        name="villa_gather",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_out,),
@@ -105,6 +106,7 @@ def villa_scatter(pages: jax.Array, table: jax.Array, updates: jax.Array, *,
     return lowered_for_platform(partial(
         pl.pallas_call,
         _scatter_kernel,
+        name="villa_scatter",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_upd,),

@@ -283,7 +283,10 @@ def main(argv=None) -> dict:
         "replicas": args.replicas,
         **summary,
         **{k: eng_stats[k] for k in ("decoded_tokens", "suspends", "resumes",
-                                     "decode_dispatches", "host_transfers")},
+                                     "decode_dispatches", "host_transfers",
+                                     "tag_reads", "prefill_reads")},
+        "preempt_suspend": s.metrics.decision_counts().get(
+            "preempt_suspend", 0),
         "villa_hit_rate": round(eng.hit_rate(), 3),
         "decode_compile_count": eng.compile_counts()["decode"],
         "ticks": s.tick_count,

@@ -94,11 +94,13 @@ def page_checksums(pages: jax.Array) -> jax.Array:
     (delta in [-255, 255] \\ {0}) shifts the sum by ``delta * w_i != 0`` —
     every single-byte corruption is detected, by construction.  Computed
     in-graph: a pack leg pays a few uint32 FLOPs per byte and no host sync.
+    Its operations carry the named scope ``movement.checksum``.
     """
-    pb = pages.shape[-2] * pages.shape[-1]
-    flat = pages.reshape(pages.shape[:-2] + (pb,)).astype(jnp.uint32)
-    w = 2 * jnp.arange(pb, dtype=jnp.uint32) + 1
-    return jnp.sum(flat * w, axis=-1, dtype=jnp.uint32)
+    with jax.named_scope("movement.checksum"):
+        pb = pages.shape[-2] * pages.shape[-1]
+        flat = pages.reshape(pages.shape[:-2] + (pb,)).astype(jnp.uint32)
+        w = 2 * jnp.arange(pb, dtype=jnp.uint32) + 1
+        return jnp.sum(flat * w, axis=-1, dtype=jnp.uint32)
 
 
 def verify_pages(pages: jax.Array, sums: jax.Array) -> jax.Array:

@@ -131,7 +131,10 @@ def execute(plan: MovementPlan, env: Env | None = None, **operands) -> Env:
     """Run every leg of ``plan`` through its registered backend.
 
     Traceable: called inside ``jax.jit`` this stages pure jax ops, so a
-    whole plan (e.g. a batched resume wave) lowers to ONE dispatch.
+    whole plan (e.g. a batched resume wave) lowers to ONE dispatch.  Each
+    leg runs under the named scope ``movement.<leg kind>``, which its
+    operations carry in their HLO metadata and, on a chip, into the
+    profiler's trace: movement's device time is told apart by scope.
     Returns the final env; callers read their result keys (``data``,
     ``cache``, ``store``, ``pool``, ...) from it.
     """
@@ -145,5 +148,6 @@ def execute(plan: MovementPlan, env: Env | None = None, **operands) -> Env:
             tr.instant(leg.kind, cat="exec",
                        attrs={"nbytes": leg.nbytes, "batch": leg.batch,
                               "hops": leg.hops})
-        env = get_backend(leg.kind)(leg, env)
+        with jax.named_scope(f"movement.{leg.kind}"):
+            env = get_backend(leg.kind)(leg, env)
     return env

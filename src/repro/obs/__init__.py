@@ -1,4 +1,5 @@
-"""One observable timeline: virtual-clock span tracing + attribution.
+"""Observability on two clocks: virtual-clock span tracing, and host spans
+on the profiler's clock.
 
 Public surface::
 
@@ -15,6 +16,12 @@ see DESIGN.md Sec. 14 for the span <-> DRAM-command-timeline mapping and
 :mod:`repro.obs.tracer` for the lane/cursor model.  Everything here is
 host bookkeeping over the virtual clock: zero device dispatches, no
 wall-clock reads (repro-lint enforced).
+
+Where the host's real time goes is the other clock:
+:func:`repro.obs.host.span` marks the tick's phases, dispatches and
+device→host reads as ``jax.profiler`` annotations, stamped by the profiler
+on its device trace's clock (import it from :mod:`repro.obs.host`, which
+needs JAX; this package does not).
 """
 from repro.obs.tracer import NULL_TRACER, NullTracer, Span, Tracer
 from repro.obs.export import chrome_trace, trace_events, write_chrome_trace

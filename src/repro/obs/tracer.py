@@ -90,7 +90,6 @@ class Tracer:
         self.spans: List[Span] = []
         self._stacks: Dict[int, List[Span]] = {}
         self._cursor: Dict[int, float] = {}
-        self._attribution: Dict[str, Dict[str, Any]] = {}
 
     # ---- clock cursors -----------------------------------------------------
 
@@ -132,9 +131,6 @@ class Tracer:
         parent = stack[-1] if stack else None
         s = Span(name, cat, lane, t0, parent, len(self.spans))
         s.attrs.update(self._clean_attrs(attrs))
-        extra = self._attribution.get(name)
-        if extra:
-            s.attrs.update(self._clean_attrs(extra))
         self.spans.append(s)
         stack.append(s)
         return s
@@ -233,16 +229,6 @@ class Tracer:
         self.end_span(mv)
         return mv
 
-    # ---- roofline binding --------------------------------------------------
-
-    def bind_attribution(self, mapping: Dict[str, Dict[str, Any]]) -> None:
-        """Attach roofline attribution to span names: every subsequent span
-        named ``k`` gains ``mapping[k]``'s entries as attrs (e.g. decode
-        spans gain the dominant HLO kernel + its byte/flop share), so the
-        trace answers "which kernel owns this tick's time"."""
-        for name, extra in mapping.items():
-            self._attribution[name] = dict(extra)
-
     # ---- aggregation -------------------------------------------------------
 
     def rollup(self) -> Dict[str, Any]:
@@ -330,9 +316,6 @@ class NullTracer:
                                             Dict[str, Any]]],
                   attrs: Optional[Dict[str, Any]] = None) -> Span:
         return self._SPAN
-
-    def bind_attribution(self, mapping: Dict[str, Dict[str, Any]]) -> None:
-        pass
 
     def rollup(self) -> Dict[str, Any]:
         return {"spans": 0, "per_phase": {}, "legs": {}}

@@ -23,6 +23,13 @@ that finally *consumes* those prices.  The paper mapping (DESIGN.md Sec. 9):
     fast-tier occupancy (a resident session reads at the fast-subarray
     timings; suspending it pays the write-through to both pools).
 
+Host time is the other clock: each tick runs inside a ``repro.tick``
+profiler span (:mod:`repro.obs.host`) with its phases — ``repro.admit``,
+the engine's ``repro.step_begin``, ``repro.plan``, ``repro.step_end``,
+``repro.wave`` — nested inside it, so a traced run shows where the host's
+real time went beside the device's.  Nothing on that clock feeds a
+decision.
+
 Time is a *virtual clock* in modeled nanoseconds: a decode tick costs
 ``decode_ns``, prefills cost ``prefill_ns_per_token`` per prompt token, and
 every movement wave is charged its occupancy-aware plan cost under the
@@ -43,6 +50,7 @@ from repro.faults.recover import (repair_row, restore_session,
                                   snapshot_sessions)
 from repro.faults.spec import FaultInjector
 from repro.obs import NULL_TRACER
+from repro.obs.host import span
 from repro.sched.metrics import Decision, JobRecord, Metrics
 from repro.sched.policy import (AdmitCand, PlaceCand, SchedContext,
                                 SchedPolicy, VictimCand, get_policy)
@@ -322,11 +330,29 @@ class Scheduler:
                        "fast_resident": bool(resident)})
 
     # ---- the tick ---------------------------------------------------------
+    def _admit(self) -> None:
+        """Admit the arrivals now due and record the tick's occupancy (the
+        ``repro.admit`` span, naming the uids it queued)."""
+        with span("repro.admit") as sp:
+            first = self._next_arrival
+            self._admit_arrivals()
+            self._record_tick()
+            if sp:
+                sp.set(uids=[a.uid for a in
+                             self._arrivals[first:self._next_arrival]])
+
+    def _record_tick(self) -> None:
+        self.metrics.record_tick(len(self.eng.active), self.eng.slots)
+
     def tick(self) -> None:
         """One controller cycle: dispatch the fused decode, prepare the next
         wave while it is in flight, sync, then execute the wave (fused
         preemption suspends, one fused resume wave, prefill submits)."""
         self.tick_count += 1
+        with span("repro.tick", tick=self.tick_count, queued=len(self.queue)):
+            self._tick()
+
+    def _tick(self) -> None:
         if (not self.eng.active and not self._has_admissible()
                 and self._next_arrival < len(self._arrivals)):
             # idle (nothing decoding, nothing placeable — queued follow-ups
@@ -334,8 +360,7 @@ class Scheduler:
             # the virtual clock to the next arrival
             self.now_ns = max(self.now_ns,
                               self._arrivals[self._next_arrival].t_ns)
-        self._admit_arrivals()
-        self.metrics.record_tick(len(self.eng.active), self.eng.slots)
+        self._admit()
         tr = self.trace
         tr.seek_all(self.now_ns)
         tick_sp = tr.begin_span("tick", lane=0, cat="tick",
@@ -363,8 +388,7 @@ class Scheduler:
                     attrs={"n_active": len(self.eng.active)})
 
         # 2. overlapped wave preparation against pre-step state
-        fast_uids = self.eng.fast_resident_uids()
-        wave = self._prepare_wave(fast_uids)
+        fast_uids, wave = self._plan()
         tr.instant("plan", lane=0, cat="plan",
                    attrs={"victims": len(wave.victims),
                           "placements": len(wave.placements)})
@@ -387,8 +411,20 @@ class Scheduler:
             self._complete_job(job, self.now_ns)
 
         # 4. execute the prepared wave
-        self.now_ns += self._execute_wave(wave, fast_uids)
+        with span("repro.wave"):
+            self.now_ns += self._execute_wave(wave, fast_uids)
         tr.end_span(tick_sp, t1_ns=max(self.now_ns, tr.now(0)))
+
+    def _plan(self):
+        """(fast-resident uids, the next wave): the policy-tag read and the
+        wave preparation, overlapped with the decode in flight (the
+        ``repro.plan`` span)."""
+        with span("repro.plan") as sp:
+            fast_uids = self.eng.fast_resident_uids()
+            wave = self._prepare_wave(fast_uids)
+            sp.set(victims=len(wave.victims),
+                   placements=len(wave.placements))
+        return fast_uids, wave
 
     def run(self, max_ticks: int = 200_000) -> Dict[str, object]:
         while self.pending():
@@ -684,18 +720,12 @@ class ClusterScheduler(Scheduler):
         self.lane_log: List[Dict[str, object]] = []
 
     # ---- the tick (parallel replica lanes) --------------------------------
-    def tick(self) -> None:
-        self.tick_count += 1
+    def _tick(self) -> None:
         if (not self.eng.active and not self._has_admissible()
                 and self._next_arrival < len(self._arrivals)):
             self.now_ns = max(self.now_ns,
                               self._arrivals[self._next_arrival].t_ns)
-        self._admit_arrivals()
-        self._fault_tick()
-        self.metrics.record_tick(
-            len(self.eng.active), self.eng.slots,
-            per_replica=[len(e.active) / e.slots
-                         for e in self.cluster.replicas])
+        self._admit()
         tr = self.trace
         tr.seek_all(self.now_ns)
         tick_sp = tr.begin_span("tick", lane=0, cat="tick",
@@ -725,8 +755,7 @@ class ClusterScheduler(Scheduler):
                     tr.seek(1 + r, tr.now(0))
 
         # 2. overlapped wave preparation against pre-step state
-        fast_uids = self.eng.fast_resident_uids()
-        wave = self._prepare_wave(fast_uids)
+        fast_uids, wave = self._plan()
         tr.instant("plan", lane=0, cat="plan",
                    attrs={"victims": len(wave.victims),
                           "placements": len(wave.placements)})
@@ -763,12 +792,22 @@ class ClusterScheduler(Scheduler):
             self._complete_job(job, self.now_ns + lanes[r])
 
         # 4. execute the prepared wave on the SAME lanes
-        self.now_ns += self._execute_wave(wave, fast_uids, lanes)
+        with span("repro.wave"):
+            self.now_ns += self._execute_wave(wave, fast_uids, lanes)
         self.lane_log.append({
             "tick": self.tick_count, "decode_ns": advance,
             "complete_lanes": seed, "lanes": tuple(lanes),
             "advance": self.now_ns - tick_t0})
         tr.end_span(tick_sp, t1_ns=max(self.now_ns, tr.now(0)))
+
+    def _record_tick(self) -> None:
+        """The chaos gate, then the tick's fleet and per-replica
+        occupancy."""
+        self._fault_tick()
+        self.metrics.record_tick(
+            len(self.eng.active), self.eng.slots,
+            per_replica=[len(e.active) / e.slots
+                         for e in self.cluster.replicas])
 
     # ---- chaos: injection, snapshots, replica recovery --------------------
     def _mech_ns(self, c: MV.MovementCost) -> float:

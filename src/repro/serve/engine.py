@@ -3,11 +3,15 @@
 The serving data path is device-resident (the serving-layer analogue of the
 paper's "move data over wide internal paths, not the narrow channel"):
 
-  * ``step`` — ONE jitted dispatch and ONE device→host transfer per decode
-    step, regardless of how ragged the slot positions are: per-slot positions
-    and the active mask are traced data (``models/lm.decode_step_batched``),
-    greedy sampling runs in-graph, and the KV cache is donated so XLA updates
-    it in place instead of copying it every token.
+  * ``step`` — ONE jitted dispatch and ONE device→host read of the decoded
+    tokens per decode step, regardless of how ragged the slot positions are:
+    per-slot positions and the active mask are traced data
+    (``models/lm.decode_step_batched``), greedy sampling runs in-graph, and
+    the KV cache is donated so XLA updates it in place instead of copying it
+    every token.  The host reads the device at two more points, each counted
+    in ``Engine.stats``: the VILLA policy tags the scheduler's cost scoring
+    consults once a tick (``tag_reads``) and each prefill's first token
+    (``prefill_reads``); ``host_transfers`` counts the decode reads alone.
   * suspend / resume — planned movement: each is a ``movement.Transfer``
     between the compute tier and the VILLA slow tier, lowered once at engine
     construction by ``movement.plan`` into pack + tier legs and executed
@@ -25,6 +29,13 @@ The movement is also *accounted*: every plan carries a ``MovementCost``
 priced by the engine's :class:`~repro.core.dram.spec.DramSpec` under the
 ``lisa`` vs ``memcpy`` mechanisms, and each suspend/resume charges its
 plan's cost — the serving-level view of Table 1's gap.
+
+Every entry point runs inside a :func:`repro.obs.host.span`
+(``repro.submit``, ``repro.step_begin``, ``repro.step_end``, ...), every
+jitted call inside ``repro.dispatch`` and every device→host read inside
+``repro.read``: with the profiler on, the trace puts the host's time
+beside the device's.  Each jit carries its function's name, so its
+programs read ``jit_<fn>`` on the device.
 
 Pure-JAX state; greedy sampling; CPU-runnable at reduced configs.
 """
@@ -45,23 +56,37 @@ from repro.core.dram.spec import DDR3_1600, DramSpec
 from repro.core.dram.villa import VillaConfig
 from repro.core.lisa import villa_cache as VC
 from repro.models import lm
+from repro.obs.host import span
 from repro.serve import paged_store as PS
 
 POS_SENTINEL = 2**30     # matches the cache init sentinel in models/lm.py
 
 
+def _named(fn, *args):
+    """``partial(fn, *args)`` under ``fn``'s name, so the program a jit of
+    it lowers reads ``jit_<fn>`` (a bare partial lowers as
+    ``jit__unknown``).  Only the name is copied: ``update_wrapper`` would
+    also set ``__wrapped__``, through which ``inspect.signature`` reports
+    the parameters the partial has already bound."""
+    p = partial(fn, *args)
+    p.__name__ = p.__qualname__ = fn.__name__
+    return p
+
+
 def _quiet(fn, *args):
-    """Run one donated-buffer dispatch, without the 'donated buffers were
-    not usable' warning on the CPU backend only: CPU XLA cannot honor
-    donation.  On every other backend a dropped donation is a real copy of
-    the buffer, so its warning stays visible.  Scoped per call so other code
-    keeps the diagnostic for its own donation mistakes."""
-    if jax.default_backend() != "cpu":
-        return fn(*args)
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        return fn(*args)
+    """Run one donated-buffer dispatch (a ``repro.dispatch`` span), without
+    the 'donated buffers were not usable' warning on the CPU backend only:
+    CPU XLA cannot honor donation.  On every other backend a dropped
+    donation is a real copy of the buffer, so its warning stays visible.
+    Scoped per call so other code keeps the diagnostic for its own donation
+    mistakes."""
+    with span("repro.dispatch", program=fn.__name__):
+        if jax.default_backend() != "cpu":
+            return fn(*args)
+        with warnings.catch_warnings():
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
+            return fn(*args)
 
 
 class EngineFull(RuntimeError):
@@ -196,7 +221,7 @@ class Engine:
             self.cache = put(lm.init_cache(cfg, slots, max_len=max_len))
         # ONE jitted decode for the whole ragged batch; the cache buffer is
         # donated — XLA writes the new KV in place instead of copying it.
-        self._decode = jax.jit(partial(lm.decode_step_batched, cfg),
+        self._decode = jax.jit(_named(lm.decode_step_batched, cfg),
                                donate_argnums=(1,))
         self._decode_legacy = None      # built on first step_unbatched()
 
@@ -209,7 +234,7 @@ class Engine:
         self._can_bucket = (not cfg.encdec and not cfg.mrope and
                             all(k in ("attn_full", "mla")
                                 for k in cfg.layer_kinds()))
-        self._prefill = jax.jit(partial(prefill_insert, cfg, max_len),
+        self._prefill = jax.jit(_named(prefill_insert, cfg, max_len),
                                 donate_argnums=(1,))
 
         # Session store: suspended KV snapshots as dtype-preserving uint8
@@ -250,15 +275,15 @@ class Engine:
         # the engine (and, through adopt_jits, every replica sharing it) in
         # a reference cycle, keeping its device buffers alive until the
         # cyclic collector happens to run
-        self._suspend = jax.jit(partial(_suspend_body, self.plan_suspend),
+        self._suspend = jax.jit(_named(_suspend_body, self.plan_suspend),
                                 donate_argnums=(1, 2))
         self._suspend_many = jax.jit(
-            partial(_suspend_many_body, self.plan_suspend, self._wave_plans),
+            _named(_suspend_many_body, self.plan_suspend, self._wave_plans),
             donate_argnums=(1, 2))
-        self._resume = jax.jit(partial(_resume_body, self.plan_resume),
+        self._resume = jax.jit(_named(_resume_body, self.plan_resume),
                                donate_argnums=(0, 1, 3))
         self._resume_many = jax.jit(
-            partial(_resume_many_body, self.plan_resume, self._wave_plans),
+            _named(_resume_many_body, self.plan_resume, self._wave_plans),
             donate_argnums=(0, 1, 3))
         # shared-row demotion: device-clone one slow row (store + checksum
         # sidecar) so a shared snapshot can yield its index without being
@@ -275,9 +300,13 @@ class Engine:
             kind="fork"), spec)
         self.plan_demote = self.plan_fork
         self.snapshot_bytes = self.page_spec.total_bytes
+        # host_transfers: decode-token reads (one per step); tag_reads and
+        # prefill_reads: the scheduler's policy-tag reads and each
+        # prefill's first token — every device→host read the engine makes
         self.stats = {"decoded_tokens": 0, "prefills": 0, "suspends": 0,
                       "resumes": 0,
                       "decode_dispatches": 0, "host_transfers": 0,
+                      "tag_reads": 0, "prefill_reads": 0,
                       "evictions": 0, "demotions": 0,
                       "forks": 0, "bytes_not_copied": 0,
                       "modeled_move_ns_lisa": 0.0,
@@ -322,28 +351,32 @@ class Engine:
         n = len(req.prompt)
         if n > self.max_len:
             raise ValueError(f"prompt length {n} exceeds max_len={self.max_len}")
-        req.generated = []
         lb = self._bucket_len(n)
-        toks = np.zeros(lb, np.int32)
-        toks[:n] = req.prompt
-        if self.cfg.mrope:      # (3,B,S) layout — unbucketed, default arange
-            positions = None
-        else:
-            pos_arr = np.full(lb, POS_SENTINEL, np.int32)
-            pos_arr[:n] = np.arange(n)
-            positions = jnp.asarray(pos_arr)[None]
-        nxt, self.cache = _quiet(
-            self._prefill, self.params, self.cache, jnp.asarray(toks)[None],
-            positions, jnp.int32(n), jnp.int32(slot))
-        req.generated.append(int(nxt))
-        self.stats["prefills"] += 1
-        self.active[slot] = req
-        self.pos[slot] = n
-        if len(req.generated) >= req.max_new:
-            # a max_new=1 request is completed by the prefill token itself —
-            # suspend now instead of letting the next step_end overshoot the
-            # budget by one decoded token
-            self.suspend(slot)
+        with span("repro.submit", uid=req.uid, bucket=lb):
+            req.generated = []
+            toks = np.zeros(lb, np.int32)
+            toks[:n] = req.prompt
+            if self.cfg.mrope:  # (3,B,S) layout — unbucketed, default arange
+                positions = None
+            else:
+                pos_arr = np.full(lb, POS_SENTINEL, np.int32)
+                pos_arr[:n] = np.arange(n)
+                positions = jnp.asarray(pos_arr)[None]
+            nxt, self.cache = _quiet(
+                self._prefill, self.params, self.cache,
+                jnp.asarray(toks)[None], positions, jnp.int32(n),
+                jnp.int32(slot))
+            with span("repro.read", what="prefill_token"):
+                req.generated.append(int(nxt))
+            self.stats["prefill_reads"] += 1
+            self.stats["prefills"] += 1
+            self.active[slot] = req
+            self.pos[slot] = n
+            if len(req.generated) >= req.max_new:
+                # a max_new=1 request is completed by the prefill token
+                # itself — suspend now instead of letting the next step_end
+                # overshoot the budget by one decoded token
+                self.suspend(slot)
         return slot
 
     def step_begin(self):
@@ -355,43 +388,50 @@ class Engine:
         with :meth:`step_end`."""
         if not self.active:
             return None
-        toks = np.zeros(self.slots, np.int32)
-        mask = np.zeros(self.slots, bool)
-        for s, req in self.active.items():
-            toks[s] = req.generated[-1]
-            mask[s] = True
-        nxt_dev, self.cache = _quiet(
-            self._decode, self.params, self.cache, jnp.asarray(toks),
-            jnp.asarray(self.pos), jnp.asarray(mask))
-        self.stats["decode_dispatches"] += 1
+        with span("repro.step_begin", active=len(self.active)):
+            toks = np.zeros(self.slots, np.int32)
+            mask = np.zeros(self.slots, bool)
+            for s, req in self.active.items():
+                toks[s] = req.generated[-1]
+                mask[s] = True
+            nxt_dev, self.cache = _quiet(
+                self._decode, self.params, self.cache, jnp.asarray(toks),
+                jnp.asarray(self.pos), jnp.asarray(mask))
+            self.stats["decode_dispatches"] += 1
         return nxt_dev
 
     def step_end(self, handle) -> List:
-        """Sync one in-flight decode (the tick's ONE device→host transfer),
-        run token bookkeeping, and suspend completed requests — a burst
-        completes as ONE fused ``suspend_many`` wave.  Returns the
-        ``(slot, request)`` pairs that completed this step."""
+        """Read one in-flight decode's tokens (the step's device→host read,
+        counted in ``host_transfers``), run token bookkeeping, and suspend
+        completed requests — a burst completes as ONE fused
+        ``suspend_many`` wave.  Returns the ``(slot, request)`` pairs that
+        completed this step."""
         if handle is None:
             return []
-        nxt = np.asarray(handle)                # the one device→host transfer
-        self.stats["host_transfers"] += 1
-        for s in self.active:
-            self.active[s].generated.append(int(nxt[s]))
-            self.pos[s] += 1
-            self.stats["decoded_tokens"] += 1
-        done = [s for s, req in self.active.items()
-                if len(req.generated) >= req.max_new]
-        completed = [(s, self.active[s]) for s in done]
-        if len(done) == 1:
-            self.suspend(done[0])
-        elif done:                        # burst completion: ONE fused wave
-            self.suspend_many(done)
+        with span("repro.step_end") as sp:
+            with span("repro.read", what="tokens"):
+                nxt = np.asarray(handle)    # waits for the decode to finish
+            self.stats["host_transfers"] += 1
+            with span("repro.bookkeep"):
+                for s in self.active:
+                    self.active[s].generated.append(int(nxt[s]))
+                    self.pos[s] += 1
+                    self.stats["decoded_tokens"] += 1
+                done = [s for s, req in self.active.items()
+                        if len(req.generated) >= req.max_new]
+                completed = [(s, self.active[s]) for s in done]
+            sp.set(completed=len(done))
+            if len(done) == 1:
+                self.suspend(done[0])
+            elif done:                    # burst completion: ONE fused wave
+                self.suspend_many(done)
         return completed
 
     def step(self) -> List:
         """Decode one token for every active slot: ONE jitted dispatch and
-        ONE device→host transfer, however ragged the slot positions are.
-        Equivalent to ``step_end(step_begin())`` with nothing overlapped."""
+        ONE device→host read of its tokens, however ragged the slot
+        positions are.  Equivalent to ``step_end(step_begin())`` with
+        nothing overlapped."""
         return self.step_end(self.step_begin())
 
     def step_unbatched(self) -> None:
@@ -409,7 +449,7 @@ class Engine:
         if not self.active:
             return
         if self._decode_legacy is None:
-            self._decode_legacy = jax.jit(partial(lm.decode_step, self.cfg))
+            self._decode_legacy = jax.jit(_named(lm.decode_step, self.cfg))
         groups: Dict[int, List[int]] = {}
         for s in self.active:
             groups.setdefault(int(self.pos[s]), []).append(s)
@@ -417,11 +457,13 @@ class Engine:
             toks = np.zeros((self.slots, 1), np.int32)
             for s in ss:
                 toks[s, 0] = self.active[s].generated[-1]
-            logits, self.cache = self._decode_legacy(
-                self.params, self.cache, jnp.asarray(toks), jnp.int32(pos))
+            logits, self.cache = _quiet(
+                self._decode_legacy, self.params, self.cache,
+                jnp.asarray(toks), jnp.int32(pos))
             self.stats["decode_dispatches"] += 1
             for s in ss:
-                nxt = int(jnp.argmax(logits[s, 0]))
+                with span("repro.read", what="tokens"):
+                    nxt = int(jnp.argmax(logits[s, 0]))
                 self.stats["host_transfers"] += 1
                 self.active[s].generated.append(nxt)
                 self.pos[s] += 1
@@ -613,17 +655,18 @@ class Engine:
         if slot not in self.active:
             raise ValueError(f"slot {slot} has no active request to suspend "
                              f"(active slots: {sorted(self.active)})")
-        uid = self._suspend_bookkeep(slot)
-        # CoW write-break BEFORE the scatter: the `unrefcounted-alias` lint
-        # rule requires the refcount API in any function that drives the
-        # _suspend scatter.
-        idx = (self.forks.write_break(uid, alloc=self._claim_row)
-               if uid in self.forks else self._claim_row(uid))
-        self._own_row(uid, idx)
-        self.sessions, self.session_sums = _quiet(
-            self._suspend, self.cache, self.sessions, self.session_sums,
-            jnp.int32(slot), jnp.int32(idx))
-        self._charge_move(self.plan_suspend)
+        with span("repro.suspend", uids=self.active[slot].uid, width=1):
+            uid = self._suspend_bookkeep(slot)
+            # CoW write-break BEFORE the scatter: the `unrefcounted-alias`
+            # lint rule requires the refcount API in any function that
+            # drives the _suspend scatter.
+            idx = (self.forks.write_break(uid, alloc=self._claim_row)
+                   if uid in self.forks else self._claim_row(uid))
+            self._own_row(uid, idx)
+            self.sessions, self.session_sums = _quiet(
+                self._suspend, self.cache, self.sessions, self.session_sums,
+                jnp.int32(slot), jnp.int32(idx))
+            self._charge_move(self.plan_suspend)
 
     def suspend_many(self, slots: Sequence[int]) -> None:
         """Suspend a wave of slots in ONE dispatch (the dual of
@@ -636,19 +679,23 @@ class Engine:
             raise ValueError(f"suspend wave needs distinct active slots "
                              f"(got {list(slots)}; active: "
                              f"{sorted(self.active)})")
-        uids = [self._suspend_bookkeep(s) for s in slots]
-        # per-uid CoW write-break (host bookkeeping; the scatter below stays
-        # ONE fused dispatch for the whole wave)
-        idxs = []
-        for uid in uids:
-            idx = (self.forks.write_break(uid, alloc=self._claim_row)
-                   if uid in self.forks else self._claim_row(uid))
-            self._own_row(uid, idx)
-            idxs.append(idx)
-        self.sessions, self.session_sums = _quiet(
-            self._suspend_many, self.cache, self.sessions, self.session_sums,
-            jnp.asarray(slots, jnp.int32), jnp.asarray(idxs, jnp.int32))
-        self._charge_move(self._wave_plan(self.plan_suspend, len(slots)))
+        with span("repro.suspend_many", width=len(slots)) as sp:
+            uids = [self._suspend_bookkeep(s) for s in slots]
+            sp.set(uids=uids)
+            # per-uid CoW write-break (host bookkeeping; the scatter below
+            # stays ONE fused dispatch for the whole wave)
+            idxs = []
+            for uid in uids:
+                idx = (self.forks.write_break(uid, alloc=self._claim_row)
+                       if uid in self.forks else self._claim_row(uid))
+                self._own_row(uid, idx)
+                idxs.append(idx)
+            self.sessions, self.session_sums = _quiet(
+                self._suspend_many, self.cache, self.sessions,
+                self.session_sums, jnp.asarray(slots, jnp.int32),
+                jnp.asarray(idxs, jnp.int32))
+            self._charge_move(self._wave_plan(self.plan_suspend,
+                                              len(slots)))
 
     def _check_resumable(self, uid: int, extra_new: int) -> int:
         for slot, r in self.active.items():
@@ -723,14 +770,15 @@ class Engine:
             raise EngineFull(f"{len(uids)} resumes requested but only "
                              f"{len(free)} slots free")
         slots = free[:len(uids)]
-        self.cache, self.sessions, self.verify_failed = _quiet(
-            self._resume_many, self.cache, self.sessions, self.session_sums,
-            self.verify_failed, jnp.asarray(slots, jnp.int32),
-            jnp.asarray(idxs, jnp.int32))
-        for slot, uid, extra in zip(slots, uids, extras):
-            self._activate(slot, uid, extra)
-            self.stats["resumes"] += 1
-        self._charge_move(self._wave_plan(self.plan_resume, len(uids)))
+        with span("repro.resume_many", uids=uids, width=len(uids)):
+            self.cache, self.sessions, self.verify_failed = _quiet(
+                self._resume_many, self.cache, self.sessions,
+                self.session_sums, self.verify_failed,
+                jnp.asarray(slots, jnp.int32), jnp.asarray(idxs, jnp.int32))
+            for slot, uid, extra in zip(slots, uids, extras):
+                self._activate(slot, uid, extra)
+                self.stats["resumes"] += 1
+            self._charge_move(self._wave_plan(self.plan_resume, len(uids)))
         return slots
 
     def _charge_move(self, plan: MV.MovementPlan) -> None:
@@ -826,14 +874,17 @@ class Engine:
 
     def fast_resident_uids(self) -> frozenset:
         """uids whose snapshots are resident in the VILLA fast tier right
-        now (one small device→host read of the policy tags).  The scheduler
-        consults this for occupancy-aware cost scoring: a resident resume is
-        a fast-subarray read, a resident suspend pays the write-through to
-        both pools.  A degraded fast tier reports empty — every movement is
-        priced at slow-tier cost."""
+        now (one small device→host read of the policy tags, counted in
+        ``tag_reads``; it waits for the last dispatch that wrote the store).
+        The scheduler consults this for occupancy-aware cost scoring: a
+        resident resume is a fast-subarray read, a resident suspend pays the
+        write-through to both pools.  A degraded fast tier reports empty —
+        every movement is priced at slow-tier cost."""
         if self.fast_degraded:
             return frozenset()
-        tags = np.asarray(self.sessions.policy.tags)
+        with span("repro.read", what="tags"):
+            tags = np.asarray(self.sessions.policy.tags)
+        self.stats["tag_reads"] += 1
         out = set()
         for t in tags:
             t = int(t)
