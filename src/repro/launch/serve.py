@@ -242,6 +242,9 @@ def main(argv=None) -> dict:
         s = sched.Scheduler(engine, policy=policy, arrivals=arrivals,
                             tracer=tracer)
         eng = engine
+    # the engines hold their own copies (bfloat16 matmul weights on a
+    # TPU): drop the float32 tree so the chip does not hold both
+    del params
 
     if fork_template_uid is not None:
         # prefill the shared prefix ONCE (max_new=1 auto-suspends at the
@@ -284,7 +287,9 @@ def main(argv=None) -> dict:
         **summary,
         **{k: eng_stats[k] for k in ("decoded_tokens", "suspends", "resumes",
                                      "decode_dispatches", "host_transfers",
-                                     "tag_reads", "prefill_reads")},
+                                     "tag_reads", "prefill_reads",
+                                     "weight_bytes_bf16",
+                                     "weight_bytes_f32")},
         "preempt_suspend": s.metrics.decision_counts().get(
             "preempt_suspend", 0),
         "villa_hit_rate": round(eng.hit_rate(), 3),

@@ -124,6 +124,31 @@ def init_lm(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32) -> Dict:
     return params
 
 
+def bf16_matmul_weights(cfg: ModelConfig, params: Dict) -> Dict:
+    """``params`` with the dense path's matmul-only weights in bfloat16:
+    each attention layer's ``wq wk wv wo``, each dense MLP's ``wi_gate
+    wi_up wo`` and an untied ``head``.  A float32 activation meets them in
+    a mixed ``x @ w``, which JAX hands to the dot as it is.  The embedding
+    table (gathered, and the head too when tied), norms, biases, and the
+    weights of MLA, MoE, mamba, RWKV and cross-attention stay as given."""
+    cast = lambda d, keys: {k: v.astype(jnp.bfloat16) if k in keys else v
+                            for k, v in d.items()}
+    out = dict(params)
+    for i, (_, group) in enumerate(stages_of(cfg)):
+        stage = dict(params[f"stage{i}"])
+        for j, (mixer_kind, mlp_kind) in enumerate(group):
+            block = dict(stage[f"b{j}"])
+            if mixer_kind in ("attn_full", "attn_local"):
+                block["mixer"] = cast(block["mixer"], ("wq", "wk", "wv", "wo"))
+            if mlp_kind == "dense":
+                block["mlp"] = cast(block["mlp"], ("wi_gate", "wi_up", "wo"))
+            stage[f"b{j}"] = block
+        out[f"stage{i}"] = stage
+    if not cfg.tie_embeddings:
+        out["head"] = params["head"].astype(jnp.bfloat16)
+    return out
+
+
 # ---------------------------------------------------------------------------
 # Cache construction.
 # ---------------------------------------------------------------------------

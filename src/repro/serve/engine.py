@@ -147,6 +147,25 @@ def session_plans(page_spec: PS.PageSpec, villa: VillaConfig,
                  for a, b in (("compute", "slow"), ("slow", "compute")))
 
 
+def rounds_f32_dots_to_bf16(device: jax.Device, rows: int) -> bool:
+    """Whether a float32 dot of ``rows`` activation rows against a weight
+    runs on ``device`` as one bfloat16 pass with float32 accumulation: on a
+    TPU at JAX's default matmul precision, when ``rows`` > 1.  XLA turns a
+    one-row dot into a float32 multiply-reduce over the float32 weight, so
+    a one-slot decode is exact float32 there.  Where it rounds, holding the
+    matmul weights in bfloat16 gives the same results without casting them
+    from float32 on every step; elsewhere (the CPU's dot is a true float32
+    dot) the engine holds them as given."""
+    return (device.platform == "tpu" and rows > 1 and
+            jax.config.jax_default_matmul_precision in (None, "default",
+                                                        "bfloat16"))
+
+
+def _held_bytes(params, dtype) -> int:
+    return sum(leaf.nbytes for leaf in jax.tree.leaves(params)
+               if leaf.dtype == dtype)
+
+
 # ---- jitted bodies of Engine (traced slot/store indices; donated buffers) --
 def _suspend_body(plan, cache, store, sums, slot, idx):
     env = MV.execute(plan, cache=cache, slot=slot, store=store, item=idx)
@@ -206,6 +225,10 @@ class Engine:
         put = partial(jax.device_put, device=self.device)
         self.cfg = cfg
         self.params = put(params)
+        if rounds_f32_dots_to_bf16(self.device, slots):
+            # decode's dots have ``slots`` rows; a prefill's have the
+            # prompt's, at least 16 where it is bucketed
+            self.params = lm.bf16_matmul_weights(cfg, self.params)
         self.spec = spec
         self.slots = slots
         self.max_len = max_len
@@ -302,7 +325,8 @@ class Engine:
         self.snapshot_bytes = self.page_spec.total_bytes
         # host_transfers: decode-token reads (one per step); tag_reads and
         # prefill_reads: the scheduler's policy-tag reads and each
-        # prefill's first token — every device→host read the engine makes
+        # prefill's first token — every device→host read the engine makes;
+        # weight_bytes_*: the params' bytes held in each dtype
         self.stats = {"decoded_tokens": 0, "prefills": 0, "suspends": 0,
                       "resumes": 0,
                       "decode_dispatches": 0, "host_transfers": 0,
@@ -310,7 +334,11 @@ class Engine:
                       "evictions": 0, "demotions": 0,
                       "forks": 0, "bytes_not_copied": 0,
                       "modeled_move_ns_lisa": 0.0,
-                      "modeled_move_ns_memcpy": 0.0}
+                      "modeled_move_ns_memcpy": 0.0,
+                      "weight_bytes_bf16": _held_bytes(self.params,
+                                                       jnp.bfloat16),
+                      "weight_bytes_f32": _held_bytes(self.params,
+                                                      jnp.float32)}
         # observability (repro.obs): fork/CoW/demotion/eviction events are
         # marked as trace instants when a tracer is attached — host
         # bookkeeping only, zero device dispatches

@@ -1,19 +1,33 @@
 """Serving engine: decode fidelity, suspension/resume, VILLA tiering."""
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.analysis import testlib as TL
-from repro.configs import get_reduced
+from repro.configs import ARCH_NAMES, get_reduced
 from repro.models import lm
-from repro.serve.engine import Engine, EngineFull, Request, UnknownSession
+from repro.serve.engine import (Engine, EngineFull, Request, UnknownSession,
+                                rounds_f32_dots_to_bf16)
 
 
 @pytest.fixture(scope="module")
 def setup():
     cfg = get_reduced("tinyllama-1.1b")
     params = lm.init_lm(cfg, jax.random.key(0))
+    return cfg, params
+
+
+@pytest.fixture(scope="module", params=["as_given", "bf16_matmul_weights"])
+def held(request, setup):
+    """The engine's params as given, and as the engine holds them on a TPU
+    (``lm.bf16_matmul_weights``): on the CPU an engine serves either as
+    given, so both must decode, suspend and resume alike."""
+    cfg, params = setup
+    if request.param == "bf16_matmul_weights":
+        params = lm.bf16_matmul_weights(cfg, params)
     return cfg, params
 
 
@@ -30,8 +44,8 @@ def _greedy_reference(cfg, params, prompt, n_new):
     return toks
 
 
-def test_engine_matches_reference_decode(setup):
-    cfg, params = setup
+def test_engine_matches_reference_decode(held):
+    cfg, params = held
     rng = np.random.default_rng(0)
     prompt = rng.integers(0, cfg.vocab_size, 12).astype(np.int32)
     eng = Engine(cfg, params, slots=2, max_len=96)
@@ -63,8 +77,8 @@ def test_engine_continuous_batching_isolation(setup):
     assert r2.generated == alone2
 
 
-def test_suspend_resume_roundtrip(setup):
-    cfg, params = setup
+def test_suspend_resume_roundtrip(held):
+    cfg, params = held
     rng = np.random.default_rng(2)
     prompt = rng.integers(0, cfg.vocab_size, 8).astype(np.int32)
     eng = Engine(cfg, params, slots=2, max_len=96, n_sessions=8)
@@ -171,10 +185,10 @@ def test_store_index_collision_evicts_explicitly(setup):
         eng.step()
 
 
-def test_suspend_resume_decode_matches_uninterrupted(setup):
+def test_suspend_resume_decode_matches_uninterrupted(held):
     """End-to-end equivalence: suspend→resume→decode produces exactly the
     tokens an uninterrupted decode would have produced."""
-    cfg, params = setup
+    cfg, params = held
     rng = np.random.default_rng(7)
     prompt = rng.integers(0, cfg.vocab_size, 9).astype(np.int32)
     straight = _greedy_reference(cfg, params, prompt, 10)
@@ -374,3 +388,60 @@ def test_villa_hit_rate_with_hot_sessions(setup):
         while eng.active:
             eng.step()
     assert eng.hit_rate() > 0.15
+
+
+# ---- held weights: bfloat16 matmul weights where the chip rounds to them ---
+
+@pytest.mark.parametrize("arch", ARCH_NAMES)
+def test_bf16_matmul_weights_casts_only_dense_path_matmuls(arch):
+    """Only the dense path's matmul-only leaves turn bfloat16: attention
+    q/k/v/o, a dense MLP's three matrices and an untied head.  Gathered
+    tables, norms, biases and the other mixers' weights stay as given."""
+    cfg = get_reduced(arch)
+    params = jax.eval_shape(lambda: lm.init_lm(cfg, jax.random.key(0)))
+    held = jax.eval_shape(partial(lm.bf16_matmul_weights, cfg), params)
+    want = set() if cfg.tie_embeddings else {("head",)}
+    for i, (_, group) in enumerate(lm.stages_of(cfg)):
+        for j, (mixer_kind, mlp_kind) in enumerate(group):
+            block = (f"stage{i}", f"b{j}")
+            if mixer_kind in ("attn_full", "attn_local"):
+                want |= {block + ("mixer", k) for k in ("wq", "wk", "wv", "wo")}
+            if mlp_kind == "dense":
+                want |= {block + ("mlp", k) for k in ("wi_gate", "wi_up", "wo")}
+    cast = {tuple(k.key for k in path)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(held)
+            if leaf.dtype == jnp.bfloat16}
+    assert cast == want
+
+
+def test_engine_off_tpu_holds_params_as_given(setup):
+    cfg, params = setup
+    eng = Engine(cfg, params, slots=2, max_len=32, n_sessions=4)
+    assert not rounds_f32_dots_to_bf16(eng.device, eng.slots)
+    leaves = jax.tree.leaves(params)
+    for a, b in zip(leaves, jax.tree.leaves(eng.params)):
+        assert a.dtype == b.dtype and np.array_equal(a, b)
+    assert eng.stats["weight_bytes_bf16"] == 0
+    assert eng.stats["weight_bytes_f32"] == sum(l.nbytes for l in leaves)
+
+
+class _Device:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+@pytest.mark.parametrize("platform,precision,rows,rounds", [
+    ("tpu", None, 4, True), ("tpu", "default", 4, True),
+    ("tpu", "bfloat16", 4, True), ("tpu", None, 2, True),
+    ("tpu", None, 1, False), ("tpu", "default", 1, False),
+    ("tpu", "highest", 4, False), ("tpu", "float32", 4, False),
+    ("tpu", "tensorfloat32", 4, False), ("cpu", None, 4, False),
+    ("gpu", None, 4, False)])
+def test_bf16_weights_only_where_f32_dots_round_to_bf16(platform, precision,
+                                                         rows, rounds):
+    """The engine holds bfloat16 matmul weights exactly where a float32
+    dot already runs as one bfloat16 pass: a TPU at the default precision,
+    with more than one row (a one-row dot is a float32 multiply-reduce).
+    It reads the precision in effect, a context manager's included."""
+    with jax.default_matmul_precision(precision):
+        assert rounds_f32_dots_to_bf16(_Device(platform), rows) is rounds

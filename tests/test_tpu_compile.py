@@ -8,7 +8,10 @@ width.  Shapes come from
 ``jax.eval_shape``, so nothing is allocated.  Each test asserts what the
 chip's compiler proves: the Pallas page kernels are present as
 ``tpu_custom_call`` (not interpret-mode stand-ins) where a kernel is
-expected, and the program fits the chip's 16 GB.
+expected, and the program fits the chip's 16 GB.  Decode and prefill
+compile twice, with the weights as given (float32) and as the engine holds
+them on the chip (``lm.bf16_matmul_weights``), which must leave no weight
+to cast on every step.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU compiler's library, and every test worker
@@ -17,6 +20,7 @@ imports this file.
 from __future__ import annotations
 
 import os
+import re
 from functools import partial
 
 import jax
@@ -28,6 +32,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.rbm_copy import villa_gather, villa_scatter
 from repro.models import lm
+from repro.roofline.hlo import split_computations
 from repro.serve import paged_store as PS
 from repro.core.dram.spec import DDR3_1600
 from repro.serve import engine as E
@@ -67,6 +72,15 @@ def model(one_chip):
     return cfg, on_chip(params), on_chip(cache)
 
 
+@pytest.fixture(scope="module")
+def held(model, one_chip):
+    """The params as the engine holds them on the chip."""
+    cfg, params, _ = model
+    return jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(partial(lm.bf16_matmul_weights, cfg), params))
+
+
 def compile_for_chip(fn, *args):
     """Compile ``fn`` for the described chip with the persistent compile
     cache off: a TPU executable written here could not be read back."""
@@ -84,6 +98,34 @@ def device_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def nbytes(tree) -> int:
+    return sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(tree))
+
+
+def weight_converts(compiled, held) -> list:
+    """The converts in ``compiled``'s optimized HLO that read or write a
+    float32 array shaped like a weight ``held`` in bfloat16 (whole, or one
+    layer of a stacked one): a weight cast on every call, down from float32
+    or up to it."""
+    shapes = set()
+    for leaf in jax.tree.leaves(held):
+        if leaf.dtype == jnp.bfloat16:
+            shapes |= {leaf.shape, leaf.shape[1:]}
+
+    def f32_weight(type_text: str) -> bool:
+        m = re.match(r"f32\[([0-9,]*)\]", type_text)
+        dims = tuple(int(d) for d in m.group(1).split(",")) if m else ()
+        while dims[:1] == (1,):
+            dims = dims[1:]
+        return m is not None and dims in shapes
+
+    return [op.name
+            for comp in split_computations(compiled.as_text()).values()
+            for op in comp.ops if op.opcode == "convert" and (
+                f32_weight(op.type_text) or
+                f32_weight(comp.types.get(op.operand_names[0], "")))]
 
 
 def pool_shapes(model, one_chip):
@@ -118,25 +160,70 @@ def test_villa_scatter_compiles_for_v5e(model, one_chip):
     assert device_bytes(c) < V5E_HBM_BYTES
 
 
-def test_full_width_decode_compiles_for_v5e(model, one_chip):
+@pytest.fixture(scope="module")
+def decode(model, held, one_chip):
+    """Full-width decode compiled with the params as given and as held:
+    {"f32": ..., "bf16": ...}."""
     cfg, params, cache = model
     vec = lambda dt: jax.ShapeDtypeStruct((SLOTS,), dt, sharding=one_chip)
-    decode = jax.jit(partial(lm.decode_step_batched, cfg),
-                     donate_argnums=(1,))
-    c = compile_for_chip(decode, params, cache, vec(jnp.int32),
-                         vec(jnp.int32), vec(jnp.bool_))
-    assert device_bytes(c) < V5E_HBM_BYTES
+    fn = jax.jit(partial(lm.decode_step_batched, cfg), donate_argnums=(1,))
+    return {name: compile_for_chip(fn, p, cache, vec(jnp.int32),
+                                   vec(jnp.int32), vec(jnp.bool_))
+            for name, p in (("f32", params), ("bf16", held))}
 
 
-def test_full_width_prefill_bucket_compiles_for_v5e(model, one_chip):
+@pytest.fixture(scope="module")
+def prefill(model, held, one_chip):
+    """One prefill bucket compiled with the params as given and as held."""
     cfg, params, cache = model
     bucket = 16                  # the synthetic workload's prompts (<= 12)
     row = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
-    prefill = jax.jit(partial(E.prefill_insert, cfg, MAX_LEN),
-                      donate_argnums=(1,))
-    c = compile_for_chip(prefill, params, cache, row, row, scalar, scalar)
-    assert device_bytes(c) < V5E_HBM_BYTES
+    fn = jax.jit(partial(E.prefill_insert, cfg, MAX_LEN), donate_argnums=(1,))
+    return {name: compile_for_chip(fn, p, cache, row, row, scalar, scalar)
+            for name, p in (("f32", params), ("bf16", held))}
+
+
+def test_full_width_decode_compiles_for_v5e(decode):
+    assert device_bytes(decode["f32"]) < V5E_HBM_BYTES
+
+
+def test_full_width_prefill_bucket_compiles_for_v5e(prefill):
+    assert device_bytes(prefill["f32"]) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_bf16_held_weights_are_not_cast_per_call_on_v5e(program, model,
+                                                         held, request):
+    """Held in bfloat16, no weight is read as float32 and cast (the f32
+    program casts them: the check sees what it looks for), and the
+    program needs at least a third of the float32 weight bytes less."""
+    compiled = request.getfixturevalue(program)
+    assert weight_converts(compiled["bf16"], held) == []
+    assert weight_converts(compiled["f32"], held) != []
+    cast = [p for p, h in zip(jax.tree.leaves(model[1]),
+                              jax.tree.leaves(held))
+            if h.dtype == jnp.bfloat16]
+    saved = device_bytes(compiled["f32"]) - device_bytes(compiled["bf16"])
+    assert saved >= nbytes(cast) / 3
+
+
+def test_one_slot_decode_reads_float32_weights_uncast_on_v5e(model, held,
+                                                             one_chip):
+    """A one-row dot is a float32 multiply-reduce: the one-slot decode
+    reads its float32 weights without rounding them to bfloat16, which is
+    why an engine with one slot holds its weights as given
+    (``engine.rounds_f32_dots_to_bf16``)."""
+    cfg, params, _ = model
+    cache = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: lm.init_cache(cfg, 1, MAX_LEN)))
+    vec = lambda dt: jax.ShapeDtypeStruct((1,), dt, sharding=one_chip)
+    fn = jax.jit(partial(lm.decode_step_batched, cfg), donate_argnums=(1,))
+    c = compile_for_chip(fn, params, cache, vec(jnp.int32), vec(jnp.int32),
+                         vec(jnp.bool_))
+    assert weight_converts(c, held) == []
+    assert not E.rounds_f32_dots_to_bf16(one_chip.device_set.pop(), 1)
 
 
 def session_store(model, one_chip):
@@ -168,13 +255,25 @@ def test_full_width_suspend_wave_compiles_for_v5e(model, one_chip):
     assert device_bytes(c) < V5E_HBM_BYTES
 
 
-def test_full_width_resume_wave_compiles_for_v5e(model, one_chip):
+@pytest.fixture(scope="module")
+def resume_wave(model, one_chip):
     cfg, params, cache = model
     store, sums, (_, plan) = session_store(model, one_chip)
     idx = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
     failed = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
     resume_many = jax.jit(partial(E._resume_many_body, plan, {}),
                           donate_argnums=(0, 1, 3))
-    c = compile_for_chip(resume_many, cache, store, sums, failed, idx, idx)
-    assert "tpu_custom_call" in c.as_text()
-    assert device_bytes(c) < V5E_HBM_BYTES
+    return compile_for_chip(resume_many, cache, store, sums, failed, idx, idx)
+
+
+def test_full_width_resume_wave_compiles_for_v5e(resume_wave):
+    assert "tpu_custom_call" in resume_wave.as_text()
+    assert device_bytes(resume_wave) < V5E_HBM_BYTES
+
+
+def test_full_width_resume_wave_fits_beside_bf16_weights_on_v5e(
+        resume_wave, model, held):
+    """The widest movement program still fits while the chip holds the
+    caller's float32 params and the engine's bfloat16-held copy."""
+    resident = nbytes(model[1]) + nbytes(held)
+    assert resident + device_bytes(resume_wave) < V5E_HBM_BYTES
