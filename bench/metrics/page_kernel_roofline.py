@@ -1,8 +1,9 @@
 """Kernels: the page kernels' share of the HBM roofline, in %.  What the
 window's suspends and resumes need is each moved session's held keys and
-values read once and written once; the time is the device time of every
-``villa_gather`` and ``villa_scatter`` kernel in the window, whatever they
-move.  Device trace."""
+values read once and written once, at the configuration's dtype
+(``bench/work.py``, the builder's counts); the time is the device time of
+every ``villa_gather`` and ``villa_scatter`` kernel in the window, whatever
+they move.  Device trace."""
 from bench import trace as T
 from bench import work
 
@@ -15,5 +16,5 @@ def read(run):
     held = sum(sum(w) for w in run.rec["suspend"] + run.rec["resume"])
     if not secs or not held:
         return None
-    need = 2 * held * work.kv_bytes_per_position(run.cfg)
+    need = 2 * held * work.kv_bytes_per_position(run)
     return 100.0 * need / run.peaks["hbm_bytes_per_s"] / secs

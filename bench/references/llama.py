@@ -5,10 +5,10 @@ describe them.
 
 Straightforward ``jax.numpy``: no cache, no batching, no kernels, nothing
 imported from the program.  It reads the benchmark's weights as published
-weights (``bench/model.py``: embedding ``stored * sqrt(hidden_size)``, norm
-weight ``1 + stored``).  One sequence at a time, padded to a fixed length
-(causal, so padding at the end changes no earlier position), attention in
-blocks of queries, the head in blocks of positions.
+weights (``bench/models/dense.py``: embedding ``stored * sqrt(hidden_size)``,
+norm weight ``1 + stored``).  One sequence at a time, padded to a fixed
+length (causal, so padding at the end changes no earlier position),
+attention in blocks of queries, the head in blocks of positions.
 
 ``dtype=float32`` with ``precision="highest"`` is the reference.  The
 control is one precision below the bfloat16 that the configurations state:

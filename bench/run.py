@@ -4,7 +4,8 @@
 
 The cell, its configuration and its traffic mix are found by name:
 ``BENCHMARK.json`` at the root of the checkout names the cell's
-``config`` (``bench/configs/<config>.json``, whose ``reference`` names its
+``config`` (``bench/configs/<config>.json``, whose ``model`` names its
+builder, ``bench/models/<model>.py``, and whose ``reference`` names its
 plain reference, ``bench/references/<reference>.py``) and its ``traffic``
 (``bench/traffic/<traffic>.json``).  Each metric the cell reports is read by
 ``bench/metrics/<name>.py`` (for a name with a dot, ``<name before the
@@ -138,11 +139,12 @@ def run_cell(bench: dict, cell: dict, conf: dict, traffic: dict, *,
     compile_cache(devs[0].platform)
     peaks = check.peaks(devs[0].device_kind, required=require_tpu)
 
-    cfg = model.model_config(conf)
+    build = model.builder(conf, root)
+    cfg = build.model_config(conf)
     key = jax.random.key(generator.seed_words(seed)[0])
     for word in generator.seed_words(seed)[1:]:
         key = jax.random.fold_in(key, word)
-    weights = model.init_weights(cfg, key)
+    weights = model.init_weights(build, cfg, key)
     jax.block_until_ready(weights)
     phases["weights"] = time.perf_counter() - T_START
     e = conf["engine"]
@@ -243,7 +245,7 @@ def run_cell(bench: dict, cell: dict, conf: dict, traffic: dict, *,
     gc.collect()
 
     run = SimpleNamespace(window=win, rec=rec, trace=tr, cfg=cfg, conf=conf,
-              peaks=peaks, setup_s=setup_s, traffic=traffic)
+              model=build, peaks=peaks, setup_s=setup_s, traffic=traffic)
     metrics = {}
     for m in cell_metrics(bench, cell["name"], trace):
         v = reader(m["name"], root)(run)

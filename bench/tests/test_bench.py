@@ -118,6 +118,7 @@ def test_cells_and_metrics_are_found_by_name():
         assert w["config"] in CONFIGS and w["traffic"] in MIXES
         conf = load("configs", w["config"])
         assert (BENCH / "references" / f"{conf['reference']}.py").is_file()
+        assert (BENCH / "models" / f"{conf['model']}.py").is_file()
     for m in SPEC["end_to_end"] + SPEC["per_layer"]:
         assert callable(run.reader(m["name"]))
     for c in SPEC["configs"]:
@@ -125,16 +126,22 @@ def test_cells_and_metrics_are_found_by_name():
         assert conf["name"] == c["name"] and conf["reduced"] == c["reduced"]
 
 
+def copy_bench(root: pathlib.Path) -> dict:
+    """A copy of the benchmark's files under ``root``, without its tests;
+    returns each file's bytes, to show that none changed."""
+    import shutil
+    shutil.copytree(BENCH, root / "bench",
+                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
+    return {p: p.read_bytes() for p in (root / "bench").rglob("*")
+            if p.is_file()}
+
+
 def test_a_new_cell_mix_and_metric_are_found_by_name(tmp_path):
     """A later PR adds a cell by adding files and entries: a copy of the
     benchmark with a new mix (``bench/traffic``), a new metric's reader
     (``bench/metrics``) and their entries in ``BENCHMARK.json`` runs the new
     cell, with no file of the copy changed."""
-    import shutil
-    shutil.copytree(BENCH, tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("tests", "__pycache__"))
-    before = {p: p.read_bytes() for p in (tmp_path / "bench").rglob("*")
-              if p.is_file()}
+    before = copy_bench(tmp_path)
     mix = dict(load("traffic", "chat_short"), burst=8)
     (tmp_path / "bench" / "traffic" / "chat_bursty.json").write_text(
         json.dumps(mix))
@@ -165,6 +172,162 @@ def test_a_new_cell_mix_and_metric_are_found_by_name(tmp_path):
     assert set(out["metrics"]) == {"setup_s", "ticks_per_s"}
     assert out["metrics"]["ticks_per_s"]["value"] > 0
     assert all(p.read_bytes() == b for p, b in before.items())
+
+
+def test_a_new_configuration_and_builder_are_found_by_name(tmp_path):
+    """A later PR adds a configuration by adding files and entries: a copy
+    of the benchmark with a new configuration that names a new builder
+    (``bench/models``, here the dense one under another name) and the
+    existing ``llama`` reference runs its cell, correct, with no file of
+    the copy changed."""
+    before = copy_bench(tmp_path)
+    models = tmp_path / "bench" / "models"
+    (models / "dense_twin.py").write_bytes((models / "dense.py").read_bytes())
+    conf = dict(load("configs", "mistral-7b-v0.3-d4"), name="mistral-twin",
+                model="dense_twin")
+    (tmp_path / "bench" / "configs" / "mistral-twin.json").write_text(
+        json.dumps(conf))
+    spec = json.loads(json.dumps(SPEC))
+    spec["configs"].append(dict(SPEC["configs"][0], name="mistral-twin",
+                                file="bench/configs/mistral-twin.json"))
+    spec["workloads"].append(dict(
+        CELLS["mistral7b-chat-short"], name="twin-chat-short",
+        config="mistral-twin"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    bench, cell, conf, t = run.load_cell(tmp_path, "twin-chat-short")
+    from bench import model
+    assert model.builder(conf, tmp_path).__file__ == str(
+        models / "dense_twin.py")
+    conf.update(TINY, engine={"slots": 4, "max_len": 256, "n_sessions": 24},
+                mean_logit_gap_limit=TINY_LIMIT,
+                widest_logit_gap_limit=TINY_LIMIT)
+    t["prompt_tokens"].update(median=40, min=8, max=100)
+    t["output_tokens"].update(median=12, min=4, max=30)
+    t.update(sessions_per_s=4.0)
+    out = run.run_cell(bench, cell, conf, t, seed=2**32 + 9, seconds=2.0,
+                       trace=False, require_tpu=False, root=tmp_path,
+                       log=lambda *a, **k: None)
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and set(out["metrics"]) == {"setup_s"}
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+# DeepSeek-V2's Hugging Face keys (latent attention; routed and shared
+# experts) at the program's reduced widths (``deepseek_v2_236b.REDUCED``)
+MLA_MOE = dict(
+    name="mla-moe-tiny", model="mla_moe", torch_dtype="bfloat16",
+    hidden_act="silu", tie_word_embeddings=False, num_hidden_layers=4,
+    hidden_size=64, num_attention_heads=4, num_key_value_heads=4,
+    intermediate_size=128, vocab_size=512, rope_theta=10000.0,
+    rms_norm_eps=1e-6, kv_lora_rank=32, q_lora_rank=48, qk_nope_head_dim=16,
+    qk_rope_head_dim=8, v_head_dim=16, n_routed_experts=8,
+    n_shared_experts=1, num_experts_per_tok=2, moe_intermediate_size=64,
+    moe_layer_freq=1)
+
+
+def test_a_latent_attention_expert_builder_brings_its_own_counts(tmp_path):
+    """A builder for a latent-attention, mixture-of-experts model is one
+    new file: its weights pass the engine's layout check, and the readers
+    that count bytes and operations read its counts (the latent K/V of a
+    position, the experts a step reaches), with no file of the copy
+    changed."""
+    import dataclasses
+    from types import SimpleNamespace
+
+    import jax
+
+    from bench import model
+    from repro.configs.deepseek_v2_236b import REDUCED
+    before = copy_bench(tmp_path)
+    (tmp_path / "bench" / "models" / "mla_moe.py").write_bytes(
+        (BENCH / "tests" / "mla_moe_builder.py").read_bytes())
+    build = model.builder(MLA_MOE, tmp_path)
+    cfg = build.model_config(MLA_MOE)
+    # REDUCED's head_dim (48) is not qk_nope + qk_rope (24), as its full
+    # CONFIG's is (192); latent layers do not read it.  The rest are the
+    # program's settings, not the model's shape.
+    shape = lambda c: {k: v for k, v in dataclasses.asdict(c).items()
+                       if k not in ("name", "head_dim", "capacity_factor",
+                                    "remat", "attn_block", "scan_chunk")}
+    assert shape(cfg) == shape(REDUCED)
+    weights = model.init_weights(build, cfg, jax.random.key(3))
+    assert weights["stage0"]["b0"]["mlp"]["wi_gate"].shape == (4, 8, 64, 64)
+
+    r = SimpleNamespace(
+        trace=synthetic(), peaks=check.peaks("TPU v5 lite"), cfg=cfg,
+        conf=MLA_MOE, model=build, window=SimpleNamespace(t0=0.0, t1=1.0),
+        rec={"decode": [(2, 30), (3, 41)], "prefill": [10, 20],
+             "suspend": [[16]], "resume": [[20]]})
+    read = {m: run.reader(m, tmp_path)(r) for m in (
+        "decode_roofline", "serve_mfu", "page_kernel_roofline")}
+    kv = build.kv_bytes_per_position(cfg, 2)
+    assert kv == 4 * (32 + 8) * 2                   # latent + rotary key
+    peak, bw = r.peaks["flops_per_s"], r.peaks["hbm_bytes_per_s"]
+    assert read["page_kernel_roofline"] == pytest.approx(
+        100 * 2 * (16 + 20) * kv / bw / T.op_time(r.trace, "villa_gather")[0])
+    steps = [build.decode_step(cfg, a, h, 2) for a, h in r.rec["decode"]]
+    least = [max(f / peak, b / bw) for f, b in steps]
+    secs, n = T.program_time(r.trace, "decode_step_batched")
+    assert read["decode_roofline"] == pytest.approx(
+        100 * sum(least) / len(least) * n / secs)
+    flops = (sum(build.prefill_flops(cfg, k) for k in (10, 20))
+             + sum(f for f, _ in steps))
+    assert read["serve_mfu"] == pytest.approx(100 * flops / peak)
+    # the dense family's counts of the same shapes read otherwise
+    dense = model.builder({"model": "dense"}, tmp_path)
+    assert dense.kv_bytes_per_position(cfg, 2) != kv
+    assert dense.decode_step(cfg, 2, 30, 2) != steps[0]
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+# the matmul parameters a decode step reads (layers and head) and the K/V
+# bytes of one position at bfloat16, counted by hand from the published
+# widths: Mistral 4 x (2 x 4096 x 4096 + 2 x 4096 x 1024 + 3 x 4096 x
+# 14336) + 4096 x 32768; DeepSeek 2 x (4 x 4096 x 4096 + 3 x 4096 x 11008)
+# + 4096 x 102400; K/V 4 x 2 x 8 x 128 x 2 and 2 x 2 x 32 x 128 x 2
+HAND_COUNTS = {"mistral-7b-v0.3-d4": (1_006_632_960, 16_384),
+               "deepseek-llm-7b-d2": (824_180_736, 32_768)}
+
+
+@pytest.mark.parametrize("name", sorted(HAND_COUNTS))
+def test_dense_counts_equal_the_hand_counts(name):
+    from types import SimpleNamespace
+
+    from bench import model, work
+    conf = load("configs", name)
+    build = model.builder(conf)
+    r = SimpleNamespace(cfg=build.model_config(conf), conf=conf,
+                        model=build)
+    params, kv = HAND_COUNTS[name]
+    p = build.param_count(r.cfg)
+    assert p["layers"] + p["head"] == params
+    assert work.value_bytes(conf) == 2
+    assert work.kv_bytes_per_position(r) == kv
+    # a step with no slot reads the weights alone, at 2 bytes each
+    assert work.decode_step(r, 0, 0) == (0.0, 2 * params)
+    assert work.decode_step(r, 1, 9)[1] == 2 * params + 2 * 4096 + 10 * kv
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_weights_are_those_of_the_seed(name):
+    """The weights of a fixed seed at reduced widths, bit for bit: the
+    checksum the harness's weights gave before the builders moved to
+    ``bench/models``."""
+    import hashlib
+
+    import jax
+
+    from bench import model
+    conf = dict(load("configs", name), **TINY)
+    build = model.builder(conf)
+    w = model.init_weights(build, build.model_config(conf),
+                           jax.random.key(2**31 - 1))
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(w)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    assert h.hexdigest() == (
+        "6d5da36b190160c9cafe4bd518db6072cfe14aaf3c36fdcfb6afece8958fe8ce")
 
 
 def test_benchmark_json_keeps_its_shape():

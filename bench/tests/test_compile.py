@@ -102,13 +102,14 @@ CASES = [(name, program) for name in sorted(CELLS) for program in PROGRAMS
 @pytest.mark.parametrize("name,program", CASES)
 def test_program_compiles_and_fits_v5e(name, program, one_chip):
     conf = json.loads((BENCH / "configs" / f"{name}.json").read_text())
-    cfg = M.model_config(conf)
+    build = M.builder(conf)
+    cfg = build.model_config(conf)
     e = conf["engine"]
     slots, max_len, n_sess = e["slots"], e["max_len"], e["n_sessions"]
     assert slots == SLOTS
     program, _, width = program.partition("@")
     params = on_chip(jax.eval_shape(
-        lambda: M._weights(cfg, jax.random.key(0))), one_chip)
+        lambda: build.weights(cfg, jax.random.key(0))), one_chip)
     cache = on_chip(jax.eval_shape(
         lambda: lm.init_cache(cfg, slots, max_len)), one_chip)
     spec = PS.PageSpec.for_cache(cache)

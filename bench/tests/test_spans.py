@@ -118,21 +118,25 @@ def test_old_trace_has_no_program_numbers(old):
 
 def test_old_trace_reads_as_before(old):
     """The harness's reduction and readers give what they gave before the
-    program had spans (values of the parent commit, on a fixed record)."""
+    program had spans (values on a fixed record).  The two that count bytes
+    read half of what they read at float32 counts (36.872517171020654 and
+    0.04038902621121944): every byte is now counted at the configuration's
+    bfloat16."""
     tr, _ = old
     conf = json.loads((BENCH / "configs" /
                        "mistral-7b-v0.3-d4.json").read_text())
     rec = {"decode": [(1, 40 + i) for i in range(49)], "prefill": [19, 23],
            "suspend": [[77]], "resume": [], "parts": {}}
+    build = model.builder(conf)
     r = SimpleNamespace(trace=tr, peaks=check.peaks("TPU v5 lite"), rec=rec,
-                        cfg=model.model_config(conf))
+                        cfg=build.model_config(conf), conf=conf, model=build)
     assert {m: run.reader(m)(r) for m in (
         "device_idle_share", "decode_roofline", "prefill_ms_per_ktok",
         "page_kernel_roofline", "resume_wave_ms")} == {
         "device_idle_share": 64.3125827191084,
-        "decode_roofline": 36.872517171020654,
+        "decode_roofline": 18.436258585510327,
         "prefill_ms_per_ktok": 781.257880952381,
-        "page_kernel_roofline": 0.04038902621121944,
+        "page_kernel_roofline": 0.02019451310560972,
         "resume_wave_ms": None}
     assert T.busy_s(tr) == 0.7204933410000001
     assert T.program_time(tr, "decode_step_batched") == (
